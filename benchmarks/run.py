@@ -14,6 +14,7 @@ import sys
 import time
 
 from benchmarks.common import CSV
+from repro.launch.compile_cache import enable_compile_cache
 
 BENCHES = {
     "fig2": ("bench_moe_topk", "throughput vs active experts under pruning"),
@@ -34,6 +35,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated subset of " + ",".join(BENCHES))
     args = ap.parse_args()
+    enable_compile_cache()
 
     names = list(BENCHES) if not args.only else args.only.split(",")
     csv = CSV()

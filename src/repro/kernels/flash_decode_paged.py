@@ -62,11 +62,25 @@ TRASH_PAGE = 0   # mirrors models/attention.py: reserved always-masked page
 # --------------------------------------------------------------------------- #
 
 
-def _gqa_kernel(bt_ref, q_ref, k_ref, v_ref, pos_ref, cur_ref, o_ref,
+def _gqa_kernel(bt_ref, cur_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
                 m_ref, l_ref, acc_ref, *, scale: float, window):
+    """One (sequence, page) cell over every KV head of the page.
+
+    q_ref   [1, g, Hkv, hd]   this sequence's queries, group-major
+    k_ref   [1, P, Hkv, hd]   page ``table[b, j]`` (all heads: the block's
+    v_ref   [1, P, Hkv, hd]   last two dims are whole, as TPU tiling needs)
+    pos_ref [1, P, 1, 1]      stored positions of the page's slots
+    m/l     [g, Hkv, 1] f32   online-softmax running max / denominator
+    acc     [g, Hkv, hd] f32  running numerator
+
+    Scores are elementwise products reduced over ``hd`` (lanes), so every
+    intermediate keeps Hkv in the sublane position: no per-head slices,
+    no transposes, and one DMA per page instead of one per (page, head).
+    """
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
+    j = pl.program_id(1)
+    nj = pl.num_programs(1)
+    g = q_ref.shape[1]
 
     @pl.when(j == 0)
     def _init():
@@ -76,32 +90,29 @@ def _gqa_kernel(bt_ref, q_ref, k_ref, v_ref, pos_ref, cur_ref, o_ref,
 
     @pl.when(bt_ref[b, j] != TRASH_PAGE)
     def _accumulate():
-        q = q_ref[0, 0].astype(jnp.float32) * scale     # [g, hd]
-        k = k_ref[0, :, 0, :]                           # [P, hd] storage dtype
-        v = v_ref[0, :, 0, :]
-        pos = pos_ref[0]                                # [P] i32
-        cur = cur_ref[0, 0]
-
-        s = jax.lax.dot_general(q, k.astype(jnp.float32),
-                                (((1,), (1,)), ((), ())))   # [g, P]
+        k = k_ref[0].astype(jnp.float32)                # [P, Hkv, hd]
+        v = v_ref[0].astype(jnp.float32)
+        pos = pos_ref[0]                                # [P, 1, 1] i32
+        cur = cur_ref[b]
         valid = (pos >= 0) & (pos <= cur)
         if window is not None:
             valid &= pos > cur - window
-        s = jnp.where(valid[None, :], s, NEG_INF)
-
-        m_old = m_ref[...]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_old - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = (acc_ref[...] * alpha
-                        + jax.lax.dot(p, v.astype(jnp.float32)))
-        m_ref[...] = m_new
+        for gi in range(g):
+            q = q_ref[0, gi].astype(jnp.float32) * scale        # [Hkv, hd]
+            s = jnp.sum(k * q[None], axis=-1, keepdims=True)    # [P, Hkv, 1]
+            s = jnp.where(valid, s, NEG_INF)
+            m_old = m_ref[gi]                                   # [Hkv, 1]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=0))
+            p = jnp.exp(s - m_new[None])                        # [P, Hkv, 1]
+            alpha = jnp.exp(m_old - m_new)
+            l_ref[gi] = l_ref[gi] * alpha + jnp.sum(p, axis=0)
+            acc_ref[gi] = acc_ref[gi] * alpha + jnp.sum(p * v, axis=0)
+            m_ref[gi] = m_new
 
     @pl.when(j == nj - 1)
     def _flush():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 def flash_decode_paged_pallas(q, kp, vp, posp, block_tables, cur_pos, *,
@@ -118,36 +129,39 @@ def flash_decode_paged_pallas(q, kp, vp, posp, block_tables, cur_pos, *,
     n_blk = block_tables.shape[1]
     scale = 1.0 / (hd ** 0.5)
 
-    qg = q.reshape(b, hkv, g, hd)
-    cur2 = cur_pos.reshape(b, 1).astype(jnp.int32)
+    # head h*g + gi of q serves kv head h; lay the group axis out first so
+    # a block's last two dims are the whole (Hkv, hd) of a page
+    qg = q.reshape(b, hkv, g, hd).transpose(0, 2, 1, 3)
+    pos4 = posp.reshape(n, p, 1, 1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, hkv, n_blk),
+        num_scalar_prefetch=2,
+        grid=(b, n_blk),
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda b_, h_, j_, bt: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, p, 1, hd),
-                         lambda b_, h_, j_, bt: (bt[b_, j_], 0, h_, 0)),
-            pl.BlockSpec((1, p, 1, hd),
-                         lambda b_, h_, j_, bt: (bt[b_, j_], 0, h_, 0)),
-            pl.BlockSpec((1, p), lambda b_, h_, j_, bt: (bt[b_, j_], 0)),
-            pl.BlockSpec((1, 1), lambda b_, h_, j_, bt: (b_, 0)),
+            pl.BlockSpec((1, g, hkv, hd), lambda b_, j_, bt, cur: (b_, 0, 0, 0)),
+            pl.BlockSpec((1, p, hkv, hd),
+                         lambda b_, j_, bt, cur: (bt[b_, j_], 0, 0, 0)),
+            pl.BlockSpec((1, p, hkv, hd),
+                         lambda b_, j_, bt, cur: (bt[b_, j_], 0, 0, 0)),
+            pl.BlockSpec((1, p, 1, 1),
+                         lambda b_, j_, bt, cur: (bt[b_, j_], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd),
-                               lambda b_, h_, j_, bt: (b_, h_, 0, 0)),
+        out_specs=pl.BlockSpec((1, g, hkv, hd),
+                               lambda b_, j_, bt, cur: (b_, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, hd), jnp.float32),
+            pltpu.VMEM((g, hkv, 1), jnp.float32),
+            pltpu.VMEM((g, hkv, 1), jnp.float32),
+            pltpu.VMEM((g, hkv, hd), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_gqa_kernel, scale=scale, window=window),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, g, hkv, hd), q.dtype),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), qg, kp, vp, posp, cur2)
-    return out.reshape(b, hq, hd)
+    )(block_tables.astype(jnp.int32), cur_pos.astype(jnp.int32), qg, kp, vp,
+      pos4)
+    return out.transpose(0, 2, 1, 3).reshape(b, hq, hd)
 
 
 # --------------------------------------------------------------------------- #
@@ -155,7 +169,7 @@ def flash_decode_paged_pallas(q, kp, vp, posp, block_tables, cur_pos, *,
 # --------------------------------------------------------------------------- #
 
 
-def _mla_kernel(bt_ref, ql_ref, qr_ref, ckv_ref, kr_ref, pos_ref, cur_ref,
+def _mla_kernel(bt_ref, cur_ref, ql_ref, qr_ref, ckv_ref, kr_ref, pos_ref,
                 o_ref, m_ref, l_ref, acc_ref, *, scale: float):
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -173,14 +187,14 @@ def _mla_kernel(bt_ref, ql_ref, qr_ref, ckv_ref, kr_ref, pos_ref, cur_ref,
         qr = qr_ref[0].astype(jnp.float32) * scale      # [H, dr]
         ckv = ckv_ref[0].astype(jnp.float32)            # [P, r]
         kr = kr_ref[0].astype(jnp.float32)              # [P, dr]
-        pos = pos_ref[0]                                # [P]
-        cur = cur_ref[0, 0]
+        pos = pos_ref[0]                                # [1, P]
+        cur = cur_ref[b]
 
         dims = (((1,), (1,)), ((), ()))
         s = (jax.lax.dot_general(ql, ckv, dims)
              + jax.lax.dot_general(qr, kr, dims))       # [H, P]
         valid = (pos >= 0) & (pos <= cur)
-        s = jnp.where(valid[None, :], s, NEG_INF)
+        s = jnp.where(valid, s, NEG_INF)
 
         m_old = m_ref[...]
         m_new = jnp.maximum(m_old, jnp.max(s, axis=1, keepdims=True))
@@ -208,22 +222,21 @@ def flash_decode_paged_mla_pallas(q_lat, q_rope, ckvp, kropep, posp,
     """
     b, h, r = q_lat.shape
     dr = q_rope.shape[-1]
-    p = ckvp.shape[1]
+    n, p = ckvp.shape[0], ckvp.shape[1]
     n_blk = block_tables.shape[1]
-    cur2 = cur_pos.reshape(b, 1).astype(jnp.int32)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(b, n_blk),
         in_specs=[
-            pl.BlockSpec((1, h, r), lambda b_, j_, bt: (b_, 0, 0)),
-            pl.BlockSpec((1, h, dr), lambda b_, j_, bt: (b_, 0, 0)),
-            pl.BlockSpec((1, p, r), lambda b_, j_, bt: (bt[b_, j_], 0, 0)),
-            pl.BlockSpec((1, p, dr), lambda b_, j_, bt: (bt[b_, j_], 0, 0)),
-            pl.BlockSpec((1, p), lambda b_, j_, bt: (bt[b_, j_], 0)),
-            pl.BlockSpec((1, 1), lambda b_, j_, bt: (b_, 0)),
+            pl.BlockSpec((1, h, r), lambda b_, j_, bt, cur: (b_, 0, 0)),
+            pl.BlockSpec((1, h, dr), lambda b_, j_, bt, cur: (b_, 0, 0)),
+            pl.BlockSpec((1, p, r), lambda b_, j_, bt, cur: (bt[b_, j_], 0, 0)),
+            pl.BlockSpec((1, p, dr),
+                         lambda b_, j_, bt, cur: (bt[b_, j_], 0, 0)),
+            pl.BlockSpec((1, 1, p), lambda b_, j_, bt, cur: (bt[b_, j_], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, h, r), lambda b_, j_, bt: (b_, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, r), lambda b_, j_, bt, cur: (b_, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
@@ -235,4 +248,5 @@ def flash_decode_paged_mla_pallas(q_lat, q_rope, ckvp, kropep, posp,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, r), jnp.float32),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), q_lat, q_rope, ckvp, kropep, posp, cur2)
+    )(block_tables.astype(jnp.int32), cur_pos.astype(jnp.int32), q_lat,
+      q_rope, ckvp, kropep, posp.reshape(n, 1, p))

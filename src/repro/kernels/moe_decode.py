@@ -22,7 +22,7 @@ expert math it organizes.  This kernel drops the dispatch stage entirely:
   * the per-token combine weight is applied to each partial product inside
     the kernel and accumulated in f32 VMEM scratch across the ``k`` slots
     and f-steps -- router-weighted combine fused with compute, flushed once
-    per token;
+    at the last grid cell;
   * ``k`` is a **static** specialization (the grid is ``(B, k, F/bf)``): a
     LExI plan's per-layer expert counts change the number of grid cells --
     i.e. the issued FLOPs -- directly, which is what converts a plan into
@@ -44,39 +44,76 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.moe_gmm import block_f_for, dequant_swiglu_tile, \
+    gate_up_specs, quant_scale_specs, quant_scale_views, swiglu_tile
 
-def _kernel(idx_ref, x_ref, w_ref, w1_ref, w2_ref, o_ref, acc_ref, *,
-            n_k_slots: int, n_f_steps: int):
+
+def _kernel(idx_ref, w_ref, x_ref, gw_ref, uw_ref, w2_ref, o_ref, acc_ref,
+            *, n_k_slots: int, n_f_steps: int):
     """One (token, k-slot, f-step) grid cell.
 
-    idx_ref               scalar-prefetch ref (consumed by the index maps)
-    x_ref   [1, D]        this token's activations
-    w_ref   [1, 1]        router combine weight of (token, slot)
-    w1_ref  [1, D, 2, bf] fused gate/up slice of expert idx[b, j]
-    w2_ref  [1, bf, D]    down-projection slice of expert idx[b, j]
-    o_ref   [1, D]        output row (written at the last slot + f-step)
-    acc_ref [1, D] f32    VMEM accumulator across slots and f-steps
+    idx_ref                  scalar-prefetch ref (consumed by the index maps)
+    w_ref   [B, k] f32 SMEM  router combine weights
+    x_ref   [B, D]           every token's activations (one resident block)
+    gw_ref  [1, D, bf]       gate columns of expert idx[b, j]'s fused w1
+    uw_ref  [1, D, bf]       up columns of the same expert
+    w2_ref  [1, bf, D]       down-projection slice of expert idx[b, j]
+    o_ref   [B, D]           output (written once, at the last cell)
+    acc_ref [B, D] f32       VMEM accumulator across tokens, slots, f-steps
+
+    The TPU tiles the last two dims of a block by (8, 128), so a one-row
+    ``[1, D]`` block of ``x`` cannot be DMA'd; the whole ``[B, D]`` batch
+    stays resident instead, and the cell multiplies all B rows by expert
+    ``idx[b, j]``'s slice and keeps row ``b``.  The weight slice is loaded
+    once either way, so the extra rows cost MXU passes, not HBM bytes.
     """
     del idx_ref
+    b = pl.program_id(0)
     j = pl.program_id(1)
     fi = pl.program_id(2)
 
-    @pl.when((j == 0) & (fi == 0))
+    @pl.when((b == 0) & (j == 0) & (fi == 0))
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.float32)                        # [1, D]
-    gate_w = w1_ref[0, :, 0, :].astype(jnp.float32)           # [D, bf]
-    up_w = w1_ref[0, :, 1, :].astype(jnp.float32)
-    gate = jax.lax.dot(x, gate_w, precision=jax.lax.Precision.DEFAULT)
-    up = jax.lax.dot(x, up_w, precision=jax.lax.Precision.DEFAULT)
-    h = jax.nn.silu(gate) * up                                # [1, bf]
-    partial = jax.lax.dot(h, w2_ref[0].astype(jnp.float32))   # [1, D]
-    acc_ref[...] += w_ref[0, 0] * partial
+    keep_row_b(acc_ref, b, w_ref[b, j] * swiglu_tile(
+        x_ref[...], gw_ref[0], uw_ref[0], w2_ref[0]))
 
-    @pl.when((j == n_k_slots - 1) & (fi == n_f_steps - 1))
+    @pl.when((b == pl.num_programs(0) - 1) & (j == n_k_slots - 1)
+             & (fi == n_f_steps - 1))
     def _flush():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def keep_row_b(acc_ref, b, partial):
+    """acc[b] += partial[b]: a row select by mask, not a dynamic slice."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, partial.shape, 0)
+    acc_ref[...] += jnp.where(rows == b, partial, 0.0)
+
+
+def decode_grid_spec(b: int, k: int, d: int, dp: int, bf: int, n_f: int,
+                     extra_specs=lambda tile_of: ()):
+    """(token, slot, f-step) grid over scalar-prefetched routed ids.
+
+    ``extra_specs(tile_of)`` appends per-expert BlockSpecs (the quantized
+    kernel's scale rows) indexed like the weight tiles."""
+    def tile_of(b_, j_, fi, idx):
+        return idx[b_, j_], fi
+
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, k, n_f),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((b, d), lambda b_, j_, fi, idx: (0, 0)),
+            *gate_up_specs(dp, bf, n_f, tile_of),
+            pl.BlockSpec((1, bf, dp),
+                         lambda b_, j_, fi, idx: (idx[b_, j_], fi, 0)),
+            *extra_specs(tile_of),
+        ],
+        out_specs=pl.BlockSpec((b, d), lambda b_, j_, fi, idx: (0, 0)),
+        scratch_shapes=[pltpu.VMEM((b, d), jnp.float32)],
+    )
 
 
 def moe_decode_pallas(x, w1, w2, idx, weights, *, block_f: int = 256,
@@ -97,33 +134,14 @@ def moe_decode_pallas(x, w1, w2, idx, weights, *, block_f: int = 256,
     assert w1.shape == (e, d, 2 * f), (w1.shape, (e, d, 2 * f))
     assert idx.shape == (b, k) and weights.shape == (b, k), \
         (idx.shape, weights.shape)
-    bf = min(block_f, f)
-    while f % bf:
-        bf //= 2
-    bf = max(bf, 1)
+    bf = block_f_for(f, block_f)
     n_f = f // bf
-
-    w1v = w1.reshape(e, d, 2, f)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, k, n_f),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda b_, j_, fi, idx: (b_, 0)),
-            pl.BlockSpec((1, 1), lambda b_, j_, fi, idx: (b_, j_)),
-            pl.BlockSpec((1, d, 2, bf),
-                         lambda b_, j_, fi, idx: (idx[b_, j_], 0, 0, fi)),
-            pl.BlockSpec((1, bf, d),
-                         lambda b_, j_, fi, idx: (idx[b_, j_], fi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, d), lambda b_, j_, fi, idx: (b_, 0)),
-        scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
-    )
     return pl.pallas_call(
         functools.partial(_kernel, n_k_slots=k, n_f_steps=n_f),
-        grid_spec=grid_spec,
+        grid_spec=decode_grid_spec(b, k, d, d, bf, n_f),
         out_shape=jax.ShapeDtypeStruct((b, d), x.dtype),
         interpret=interpret,
-    )(idx.astype(jnp.int32), x, weights.astype(jnp.float32), w1v, w2)
+    )(idx.astype(jnp.int32), weights.astype(jnp.float32), x, w1, w1, w2)
 
 
 def _lookahead_gather(w, idx, pred_idx):
@@ -160,15 +178,20 @@ def moe_decode_routed_jnp(x, w1, w2, idx, weights, pred_idx=None):
 
     ``pred_idx`` (router lookahead, [B, k] i32) stages the gathers on ids
     available before this layer's router runs; see ``_lookahead_gather``.
+
+    It rounds where the kernel does (``swiglu_tile``): f32-accumulating
+    dots on the weights' dtype, ``h`` cast to that dtype before the
+    down-projection, and the combine an elementwise f32 multiply-add.
     """
     w1g = _gather(w1, idx, pred_idx)                          # [B, k, D, 2F]
     w2g = _gather(w2, idx, pred_idx)                          # [B, k, F, D]
-    h = jnp.einsum("bd,bkdf->bkf", x.astype(jnp.float32),
-                   w1g.astype(jnp.float32))
+    h = jnp.einsum("bd,bkdf->bkf", x.astype(w1.dtype), w1g,
+                   preferred_element_type=jnp.float32)
     gate, up = jnp.split(h, 2, axis=-1)
-    h = jax.nn.silu(gate) * up                                # [B, k, F]
-    y = jnp.einsum("bkf,bkfd,bk->bd", h, w2g.astype(jnp.float32),
-                   weights.astype(jnp.float32))
+    h = (jax.nn.silu(gate) * up).astype(w2.dtype)             # [B, k, F]
+    y = jnp.einsum("bkf,bkfd->bkd", h, w2g,
+                   preferred_element_type=jnp.float32)
+    y = jnp.sum(y * weights.astype(jnp.float32)[..., None], axis=1)
     return y.astype(x.dtype)
 
 
@@ -177,81 +200,33 @@ def moe_decode_routed_jnp(x, w1, w2, idx, weights, pred_idx=None):
 # --------------------------------------------------------------------------- #
 
 
-def _unpack_int4_cols(p32, axis: int):
-    """int8-packed nibble pairs -> two int32 half-arrays (lo, hi).
-
-    Blocked-halves layout (``models/moe/params.py``): byte i along
-    ``axis`` packs element i (low nibble, ``(x ^ 8) - 8`` sign-extend)
-    and element i + n//2 (high nibble, arithmetic-shift sign-extend).
-    """
-    del axis  # packed axis is implicit: the caller slices/concats
-    lo = ((p32 & 0xF) ^ 8) - 8
-    hi = p32 >> 4
-    return lo, hi
-
-
-def _quant_kernel(idx_ref, x_ref, w_ref, w1_ref, w2_ref, s1_ref, s2_ref,
-                  o_ref, acc_ref, *, n_k_slots: int, n_f_steps: int,
-                  packed: bool):
+def _quant_kernel(idx_ref, w_ref, x_ref, gw_ref, uw_ref, w2_ref, s1g_ref,
+                  s1u_ref, s2_ref, o_ref, acc_ref, *, n_k_slots: int,
+                  n_f_steps: int, packed: bool):
     """One (token, k-slot, f-step) grid cell over int8-stored tiles.
 
     Same walk as ``_kernel``; the expert tiles arrive int8 (int4: packed
     two-per-byte along D) with their scale rows sliced by the *same*
-    scalar-prefetched index maps:
-
-    w1_ref  [1, D(p), 2, bf] int8   fused gate/up tile of expert idx[b, j]
-    w2_ref  [1, bf, D(p)]   int8    down-projection tile
-    s1_ref  [1, 2, bf] f32          per-(gate|up, f-column) scales
-    s2_ref  [1, bf] f32             per-f-row scales
-
-    Dequant placement follows the scale layout: s1 multiplies *after* the
-    x @ w1q dots (constant along the D contraction), s2 folds into ``h``
-    *before* the h @ w2q dot (it varies along the F contraction and
-    cannot move past it).  Accumulation stays f32 in VMEM -- identical to
-    the bf16 path's numerics once tiles are dequantized.
+    scalar-prefetched index maps (``dequant_swiglu_tile`` in
+    ``kernels/moe_gmm.py`` has the dequant placement).
     """
     del idx_ref
+    b = pl.program_id(0)
     j = pl.program_id(1)
     fi = pl.program_id(2)
 
-    @pl.when((j == 0) & (fi == 0))
+    @pl.when((b == 0) & (j == 0) & (fi == 0))
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.float32)                        # [1, D]
-    if packed:
-        d_half = x.shape[1] // 2
-        lo1, hi1 = _unpack_int4_cols(w1_ref[0].astype(jnp.int32), 0)
-        gate = (jax.lax.dot(x[:, :d_half], lo1[:, 0, :].astype(jnp.float32))
-                + jax.lax.dot(x[:, d_half:], hi1[:, 0, :].astype(jnp.float32)))
-        up = (jax.lax.dot(x[:, :d_half], lo1[:, 1, :].astype(jnp.float32))
-              + jax.lax.dot(x[:, d_half:], hi1[:, 1, :].astype(jnp.float32)))
-    else:
-        w1f = w1_ref[0].astype(jnp.float32)                   # [D, 2, bf]
-        gate = jax.lax.dot(x, w1f[:, 0, :])
-        up = jax.lax.dot(x, w1f[:, 1, :])
-    gate = gate * s1_ref[0, 0, :]
-    up = up * s1_ref[0, 1, :]
-    h = jax.nn.silu(gate) * up * s2_ref[0, :]                 # [1, bf]
-    if packed:
-        lo2, hi2 = _unpack_int4_cols(w2_ref[0].astype(jnp.int32), 1)
-        partial = jnp.concatenate(
-            [jax.lax.dot(h, lo2.astype(jnp.float32)),
-             jax.lax.dot(h, hi2.astype(jnp.float32))], axis=-1)
-    else:
-        partial = jax.lax.dot(h, w2_ref[0].astype(jnp.float32))  # [1, D]
-    acc_ref[...] += w_ref[0, 0] * partial
+    keep_row_b(acc_ref, b, w_ref[b, j] * dequant_swiglu_tile(
+        x_ref[...], gw_ref[0], uw_ref[0], w2_ref[0], s1g_ref[0, 0],
+        s1u_ref[0, 0], s2_ref[0], packed=packed))
 
-    @pl.when((j == n_k_slots - 1) & (fi == n_f_steps - 1))
+    @pl.when((b == pl.num_programs(0) - 1) & (j == n_k_slots - 1)
+             & (fi == n_f_steps - 1))
     def _flush():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
-
-
-def _block_f(f: int, block_f: int) -> int:
-    bf = min(block_f, f)
-    while f % bf:
-        bf //= 2
-    return max(bf, 1)
 
 
 def moe_decode_quant_pallas(x, w1q, w2q, s1, s2, idx, weights, *,
@@ -265,8 +240,8 @@ def moe_decode_quant_pallas(x, w1q, w2q, s1, s2, idx, weights, *,
 
     The scale rows ride the same scalar-prefetched routed ids as the
     weight tiles: per (token, slot, f-step) grid cell the BlockSpec index
-    maps DMA expert ``idx[b, j]``'s quantized tile *and* its (1, 2, bf) /
-    (1, bf) scale slices -- quantization adds no second indexing scheme.
+    maps DMA expert ``idx[b, j]``'s quantized tile *and* its gate/up/down
+    scale slices -- quantization adds no second indexing scheme.
     """
     if dtype not in ("int8", "int4"):
         raise ValueError(f"unsupported expert dtype {dtype!r}")
@@ -281,36 +256,19 @@ def moe_decode_quant_pallas(x, w1q, w2q, s1, s2, idx, weights, *,
     assert not packed or d % 2 == 0, d
     assert idx.shape == (b, k) and weights.shape == (b, k), \
         (idx.shape, weights.shape)
-    bf = _block_f(f, block_f)
+    bf = block_f_for(f, block_f)
     n_f = f // bf
-
-    w1v = w1q.reshape(e, dp, 2, f)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, k, n_f),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda b_, j_, fi, idx: (b_, 0)),
-            pl.BlockSpec((1, 1), lambda b_, j_, fi, idx: (b_, j_)),
-            pl.BlockSpec((1, dp, 2, bf),
-                         lambda b_, j_, fi, idx: (idx[b_, j_], 0, 0, fi)),
-            pl.BlockSpec((1, bf, dp),
-                         lambda b_, j_, fi, idx: (idx[b_, j_], fi, 0)),
-            pl.BlockSpec((1, 2, bf),
-                         lambda b_, j_, fi, idx: (idx[b_, j_], 0, fi)),
-            pl.BlockSpec((1, bf),
-                         lambda b_, j_, fi, idx: (idx[b_, j_], fi)),
-        ],
-        out_specs=pl.BlockSpec((1, d), lambda b_, j_, fi, idx: (b_, 0)),
-        scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
-    )
+    s1v, s2v = quant_scale_views(s1, s2)
     return pl.pallas_call(
         functools.partial(_quant_kernel, n_k_slots=k, n_f_steps=n_f,
                           packed=packed),
-        grid_spec=grid_spec,
+        grid_spec=decode_grid_spec(
+            b, k, d, dp, bf, n_f,
+            extra_specs=lambda tile_of: quant_scale_specs(bf, tile_of)),
         out_shape=jax.ShapeDtypeStruct((b, d), x.dtype),
         interpret=interpret,
-    )(idx.astype(jnp.int32), x, weights.astype(jnp.float32), w1v, w2q,
-      s1.astype(jnp.float32), s2.astype(jnp.float32))
+    )(idx.astype(jnp.int32), weights.astype(jnp.float32), x, w1q, w1q, w2q,
+      s1v, s1v, s2v)
 
 
 def moe_decode_routed_quant_jnp(x, w1q, w2q, s1, s2, idx, weights, *,

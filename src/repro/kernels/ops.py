@@ -1,9 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute with ``interpret=True`` -- the
-kernel body runs in Python on CPU for correctness validation; on TPU the same
-code lowers to Mosaic.  Model code calls these wrappers, never pallas_call
-directly.
+On the CPU backend the kernels execute with ``interpret=True`` (or, for the
+two decode kernels, a jnp path with identical semantics) -- the kernel body
+runs in Python for correctness validation; on TPU the same code lowers to
+Mosaic.  Any other backend is refused rather than silently interpreted.
+Model code calls these wrappers, never pallas_call directly.
 """
 
 from __future__ import annotations
@@ -18,7 +19,12 @@ from repro.kernels.moe_ffn import moe_ffn_pallas
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """True on the CPU backend (interpret / jnp branch), False on TPU."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas kernels run on TPU (Mosaic) or CPU "
+                           f"(interpret); backend {backend!r} is neither")
+    return backend == "cpu"
 
 
 @partial(jax.jit, static_argnames=("block_c", "block_f"))

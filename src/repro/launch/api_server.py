@@ -32,24 +32,26 @@ import json
 import jax
 import numpy as np
 
-from repro import models
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.serve import serving_opts
 from repro.serving import ApiServer, Engine
+from repro.serving.runner import BASE_PLAN, init_serving_params
 
 
 def build_engine(args) -> Engine:
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    params = models.init_params(jax.random.PRNGKey(args.seed), cfg)
-    eng = Engine(cfg, params, max_batch=args.max_batch, max_len=args.max_len,
+    # split-layout weights: the engine holds the only copy
+    eng = Engine(cfg, init_serving_params(jax.random.PRNGKey(args.seed), cfg),
+                 max_batch=args.max_batch, max_len=args.max_len,
                  num_pages=args.num_pages,
-                 use_kernel=args.use_kernel or None,
-                 use_moe_decode=args.use_moe_decode or None,
                  expert_dtype=args.expert_dtype,
                  prefix_cache=args.prefix_cache,
                  scheduler=args.scheduler,
-                 admission=args.admission)
+                 admission=args.admission,
+                 opts=serving_opts(args))
     if args.plan is not None:
         from repro.core import LexiPlan
         eng.add_plan("lexi", LexiPlan.load(args.plan))
@@ -58,8 +60,9 @@ def build_engine(args) -> Engine:
         from repro.core import optimize
         n = cfg.num_moe_layers
         budget = max(n, int(round(args.lexi_budget_frac * n * cfg.moe_top_k)))
-        eng.add_plan("lexi", optimize(params, cfg, budget, method="dp",
-                                      n_iter=4, profile_batch=2,
+        eng.add_plan("lexi", optimize(eng.runner.params,
+                                      eng.runner.cfg_for(BASE_PLAN), budget,
+                                      method="dp", n_iter=4, profile_batch=2,
                                       profile_seq=32))
     return eng
 
@@ -109,8 +112,12 @@ def main() -> int:
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=256)
     ap.add_argument("--num-pages", type=int, default=None)
-    ap.add_argument("--use-kernel", action="store_true")
-    ap.add_argument("--use-moe-decode", action="store_true")
+    ap.add_argument("--moe-impl", choices=["gmm", "dense"], default="gmm")
+    ap.add_argument("--use-kernel", action="store_true",
+                    help="Pallas kernels: paged flash-decode and the gmm "
+                         "grouped expert matmul")
+    ap.add_argument("--use-moe-decode", action="store_true",
+                    help="fused routed-expert MoE on decode steps")
     ap.add_argument("--expert-dtype", choices=["bf16", "int8", "int4"],
                     default="bf16")
     ap.add_argument("--prefix-cache", action="store_true")
@@ -129,6 +136,7 @@ def main() -> int:
                          "completion in-process, shut down, exit")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     eng = build_engine(args)
     vocab = eng.cfg.vocab_size

@@ -17,17 +17,29 @@ inter-pod links -- nothing but gradient all-reduce ever crosses it.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    JAX's default is now explicit sharding, under which a reshape of a
+    sharded operand must state its output sharding.  The model code leaves
+    that to the partitioner (GSPMD propagation), so every mesh here is
+    built with automatic axes.
+    """
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh for subprocess tests (host platform devices)."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def chips(mesh) -> int:

@@ -30,9 +30,20 @@ import argparse
 import jax
 import numpy as np
 
-from repro import models
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models.opts import ModelOpts
 from repro.serving import Engine, Request
+from repro.serving.runner import BASE_PLAN, init_serving_params
+
+
+def serving_opts(args) -> ModelOpts:
+    """The MoE path the launchers serve: ``--moe-impl`` (dropless ``gmm``
+    by default, never the capacity-dropping ``dense`` by accident) with
+    the Pallas kernels ``--use-kernel`` / ``--use-moe-decode`` turn on."""
+    return ModelOpts(moe_impl=args.moe_impl, use_moe_kernel=args.use_kernel,
+                     use_paged_kernel=args.use_kernel,
+                     use_moe_decode_kernel=args.use_moe_decode)
 
 
 def synth_requests(n: int, vocab: int, *, lo: int = 8, hi: int = 48,
@@ -97,9 +108,15 @@ def main() -> int:
                          "(default: on for the paged layout); "
                          "--no-preemption reserves prompt+max_new pages for "
                          "a request's whole lifetime at admission")
+    ap.add_argument("--moe-impl", choices=["gmm", "dense"], default="gmm",
+                    help="MoE dispatch: dropless sort-based gmm, or the "
+                         "capacity-buffer dense reference (drops tokens "
+                         "past capacity)")
     ap.add_argument("--use-kernel", action="store_true",
-                    help="paged decode attends pages in-kernel (block-table-"
-                         "native flash-decode) instead of gathering")
+                    help="Pallas kernels: paged decode attends pages "
+                         "in-kernel (block-table-native flash-decode) "
+                         "instead of gathering, and gmm runs the grouped "
+                         "expert matmul kernel")
     ap.add_argument("--use-moe-decode", action="store_true",
                     help="decode steps run MoE through the fused "
                          "routed-expert path (no sort plan) instead of the "
@@ -154,28 +171,31 @@ def main() -> int:
                          "is declared but inert)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    params = models.init_params(jax.random.PRNGKey(args.seed), cfg)
     req_kw = dict(max_new=args.max_new, seed=args.seed,
                   temperature=args.temperature, top_k=args.top_k)
     reqs = synth_requests(args.requests, cfg.vocab_size, **req_kw)
 
-    eng = Engine(cfg, params, max_batch=args.max_batch, max_len=args.max_len,
+    # weights made in the runner's split layout: the engine holds the
+    # only copy (no regrouped duplicate, no launcher-held original)
+    eng = Engine(cfg, init_serving_params(jax.random.PRNGKey(args.seed), cfg),
+                 max_batch=args.max_batch, max_len=args.max_len,
                  prefill_chunk=args.prefill_chunk,
                  cache_layout=args.cache_layout,
                  num_pages=args.num_pages,
                  preemption=args.preemption,
-                 use_kernel=args.use_kernel or None,
-                 use_moe_decode=args.use_moe_decode or None,
                  expert_dtype=args.expert_dtype,
                  router_lookahead=args.router_lookahead or None,
                  prefix_cache=args.prefix_cache,
                  scheduler=args.scheduler,
                  admission=args.admission,
-                 degrade_under_pressure=args.degrade_under_pressure)
+                 degrade_under_pressure=args.degrade_under_pressure,
+                 opts=serving_opts(args))
+
     def arrivals():
         if args.open_loop_rate <= 0:
             return None
@@ -192,7 +212,7 @@ def main() -> int:
 
     print(f"arch={cfg.name} baseline top-k={cfg.moe_top_k or 'n/a'} "
           f"layout={eng.kv.layout} chunk={eng.prefill_chunk or 'whole'} "
-          f"experts={args.expert_dtype}")
+          f"moe={args.moe_impl} experts={args.expert_dtype}")
     eng.serve(reqs, **serve_kw)
     tput = _report("baseline", eng)
 
@@ -205,8 +225,9 @@ def main() -> int:
         from repro.core import optimize
         n = cfg.num_moe_layers
         budget = max(n, int(round(args.lexi_budget_frac * n * cfg.moe_top_k)))
-        plan = optimize(params, cfg, budget, method="dp", n_iter=4,
-                        profile_batch=2, profile_seq=32)
+        plan = optimize(eng.runner.params, eng.runner.cfg_for(BASE_PLAN),
+                        budget, method="dp", n_iter=4, profile_batch=2,
+                        profile_seq=32)
         if args.save_plan:
             plan.save(args.save_plan)
             print(f"saved plan -> {args.save_plan}")

@@ -236,12 +236,15 @@ def _mask_bias(q_pos, kv_pos, window: Optional[int], causal: bool):
     return jnp.where(valid, 0.0, NEG_INF).astype(jnp.float32)
 
 
-def _sdpa(q, k, v, bias, scale: float, compute_dtype: str = "f32"):
+def _sdpa(q, k, v, bias, scale: float, compute_dtype: str = "f32",
+          precision=None):
     """Grouped-query attention: q [B,Sq,Hq,d], k/v [B,Sk,Hkv,d(v)].
 
     ``compute_dtype="bf16_accum32"`` keeps K/V operands in their storage
     dtype with f32 accumulation (preferred_element_type) -- on TPU this is
     MXU-native and halves the HBM bytes of reading a bf16 KV cache (§Perf).
+    ``precision`` applies to the f32 dots: at TPU's default a dot rounds
+    its f32 operands (the probabilities) to bf16; ``HIGHEST`` does not.
     """
     b, sq, hq, dq = q.shape
     hkv = k.shape[2]
@@ -257,10 +260,12 @@ def _sdpa(q, k, v, bias, scale: float, compute_dtype: str = "f32"):
                          preferred_element_type=jnp.float32)
     else:
         scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
-                            k.astype(jnp.float32)) * scale
+                            k.astype(jnp.float32),
+                            precision=precision) * scale
         scores = scores + bias[:, None]                 # [B,Hkv,g,Sq,Sk]
         probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v.astype(jnp.float32))
+        out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v.astype(jnp.float32),
+                         precision=precision)
     return out.reshape(b, sq, hq, v.shape[-1]).astype(q.dtype)
 
 
@@ -347,8 +352,7 @@ def _decode_attend_seqshard(cfg: ModelConfig, q, k_new, v_new, pos_b, cache,
     pspec = P(bdim, "model")
     bspec3 = P(bdim, None, None)
     bspec1 = P(bdim)
-    from repro.models.common import shard_map
-    out, k2, v2, p2 = shard_map(
+    out, k2, v2, p2 = jax.shard_map(
         body, mesh=mesh,
         in_specs=(qspec, bspec3, bspec3, bspec1, cspec, cspec, pspec),
         out_specs=(qspec, cspec, cspec, pspec),
@@ -472,8 +476,11 @@ def gqa_attention(
             else:
                 bias = _mask_bias(pos_b[:, None], kv_pos, cfg.sliding_window,
                                   causal)
+                # the paged kernel's oracle: it does exact f32 math on the
+                # VPU, so the dots here must not round probs to bf16
                 out = _sdpa(q, k_all, v_all, bias, 1.0 / (hd ** 0.5),
-                            compute_dtype)
+                            compute_dtype,
+                            precision=jax.lax.Precision.HIGHEST)
         new_cache = cache
     elif mode == "chunk":
         # chunked prefill: attend against the PRE-write cache plus the

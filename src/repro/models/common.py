@@ -10,13 +10,6 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 
-# ``jax.shard_map`` graduated from jax.experimental after 0.4.x; both spell
-# mesh/in_specs/out_specs as keywords, so callers import the shim from here.
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
 # --------------------------------------------------------------------------- #
 # dtype policy
 # --------------------------------------------------------------------------- #

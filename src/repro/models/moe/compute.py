@@ -39,6 +39,12 @@ def grouped_ffn(w1, w2, xs, plan: SortPlan, use_kernel: bool = False):
     be the obvious spelling but lowers to an O(M*E*D*F) masked dot on CPU).
     Padding rows are zero and SwiGLU(0)*0 @ w2 == 0, so no masking is
     needed in either path.
+
+    The jnp path rounds where the kernels do (``kernels/moe_gmm.py``
+    ``swiglu_tile``): dots accumulate in f32, SwiGLU runs in f32, ``h``
+    is cast to the weights' dtype before the down-projection, and the f32
+    result goes to the combine unrounded -- so on the chip it is the
+    kernels' oracle to f32 summation order, not to bf16 rounding.
     """
     if use_kernel:
         from repro.kernels import ops as kops
@@ -46,10 +52,12 @@ def grouped_ffn(w1, w2, xs, plan: SortPlan, use_kernel: bool = False):
                             block_m=plan.block_m)
     m, d = xs.shape
     xt = xs.reshape(-1, plan.block_m, d)              # [n_tiles, bm, D]
-    h = jnp.einsum("tbd,tdf->tbf", xt, w1[plan.tile_expert])
+    h = jnp.einsum("tbd,tdf->tbf", xt, w1[plan.tile_expert],
+                   preferred_element_type=jnp.float32)
     gate, up = jnp.split(h, 2, axis=-1)
-    h = jax.nn.silu(gate) * up
-    yt = jnp.einsum("tbf,tfd->tbd", h, w2[plan.tile_expert])
+    h = (jax.nn.silu(gate) * up).astype(w2.dtype)
+    yt = jnp.einsum("tbf,tfd->tbd", h, w2[plan.tile_expert],
+                    preferred_element_type=jnp.float32)
     return yt.reshape(m, d)
 
 

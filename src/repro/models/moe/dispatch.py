@@ -153,8 +153,12 @@ def sort_dispatch(x2d, plan: SortPlan, top_k: int):
 
 
 def sort_combine(ys, weights, plan: SortPlan):
-    """ys [M, D] -> y [T, D]: unsort via the same dest map, weighted sum."""
+    """ys [M, D] -> y [T, D] f32: unsort via the same dest map, weighted sum.
+
+    The weighting is an elementwise f32 multiply-add, as in the fused
+    decode kernel's accumulator: spelled as a dot, TPU's default precision
+    would round both f32 operands to bf16 first.
+    """
     t, k = weights.shape
-    gathered = ys[plan.dest].reshape(t, k, -1)
-    return jnp.einsum("tkd,tk->td", gathered.astype(jnp.float32),
-                      weights.astype(jnp.float32))
+    gathered = ys[plan.dest].reshape(t, k, -1).astype(jnp.float32)
+    return jnp.sum(gathered * weights.astype(jnp.float32)[..., None], axis=1)

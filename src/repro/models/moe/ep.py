@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.models.common import shard_map
 from repro.models.moe.compute import add_shared, expert_ffn
 from repro.models.moe.dispatch import _gather_combine, _scatter, _slot_positions
 from repro.models.moe.router import capacity, route
@@ -118,7 +117,7 @@ def moe_ep_a2a(params: Dict, cfg: ModelConfig, x2d, top_k: int, *, mesh,
                    model_axis=model_axis, model_size=model_size,
                    all_axes=all_axes, use_kernel=use_kernel,
                    a2a_chunks=a2a_chunks)
-    return shard_map(
+    return jax.shard_map(
         lambda p, xx: body(p, x_local=xx),
         mesh=mesh,
         in_specs=(_ep_param_specs(params, model_axis),
@@ -137,7 +136,7 @@ def moe_ep_psum(params: Dict, cfg: ModelConfig, x2d, top_k: int, *, mesh,
     body = partial(moe_ep_psum_local, cfg=cfg, top_k=top_k,
                    model_axis=model_axis, model_size=model_size,
                    token_axes=token_axes, use_kernel=use_kernel)
-    return shard_map(
+    return jax.shard_map(
         lambda p, xx: body(p, x_rep=xx),
         mesh=mesh,
         in_specs=(_ep_param_specs(params, model_axis), P(token_axes, None)),

@@ -138,8 +138,8 @@ def moe(params: Dict, cfg: ModelConfig, x, top_k: int, *,
         expert_dtype: str = "bf16", pred_idx=None, k_budget=None):
     """x [B, S, D] -> (y [B, S, D], aux_loss scalar).
 
-    ``impl`` overrides ``cfg.moe_impl``; mesh-requiring impls fall back to
-    ``dense`` when no mesh is given (single-device runs of EP configs).
+    ``impl`` overrides ``cfg.moe_impl``; mesh-requiring impls raise when
+    no mesh is given (pick ``gmm`` for a single-device run).
     ``decode_kernel=True`` opts decode-shaped gmm calls
     (``T <= DECODE_TOKEN_THRESHOLD``) into the fused routed-expert path.
     ``expert_dtype`` != "bf16" expects params quantized at load
@@ -157,7 +157,8 @@ def moe(params: Dict, cfg: ModelConfig, x, top_k: int, *,
         raise ValueError(f"unknown moe impl {impl!r}; have {available_impls()}")
     fn, needs_mesh = _IMPLS[impl]
     if needs_mesh and mesh is None:
-        fn, _ = _IMPLS["dense"]
+        raise ValueError(f"moe impl {impl!r} shards experts over a mesh's "
+                         "'model' axis; pass mesh= or choose 'gmm'")
     y2d, aux = fn(params, cfg, x2d, top_k, mesh=mesh, use_kernel=use_kernel,
                   a2a_chunks=a2a_chunks, expert_dtype=expert_dtype,
                   pred_idx=pred_idx, k_budget=k_budget)

@@ -42,6 +42,8 @@ import json
 import math
 import queue
 import threading
+import time
+from contextlib import contextmanager
 from dataclasses import asdict
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -153,6 +155,10 @@ class ApiServer:
         #: THE engine lock: every touch of the engine -- step, submit,
         #: cancel, stats -- happens under it, from whichever thread
         self.lock = threading.Lock()
+        #: control actions queued for the lock; the pump lets them in
+        #: before its next step, so back-to-back steps cannot starve them
+        self._queued = 0
+        self._queued_lock = threading.Lock()
         self._wake = threading.Event()      # submission -> pump wakes
         self._stop = threading.Event()
         self._live: Dict[int, _Completion] = {}     # uid -> waiting conn
@@ -225,6 +231,8 @@ class ApiServer:
         pending at all (a fresh submission sets it)."""
         eng = self.engine
         while not self._stop.is_set():
+            while self._queued and not self._stop.is_set():
+                time.sleep(0)
             with self.lock:
                 self._wake.clear()
                 nxt = eng.next_arrival()
@@ -248,6 +256,22 @@ class ApiServer:
     # ------------------------------------------------------------------ #
     # Handler-facing control plane (each call takes the lock briefly)
     # ------------------------------------------------------------------ #
+    @contextmanager
+    def _control(self):
+        """The engine lock for one control action, taken ahead of the
+        pump's next step."""
+        with self._queued_lock:
+            self._queued += 1
+        try:
+            self.lock.acquire()
+        finally:
+            with self._queued_lock:
+                self._queued -= 1
+        try:
+            yield
+        finally:
+            self.lock.release()
+
     def submit(self, body: Any) -> Tuple[int, _Completion, bool]:
         """Validate and submit one completion request; returns
         ``(uid, completion queue, streaming?)``.  Uids are server-
@@ -255,7 +279,7 @@ class ApiServer:
         kw = _parse_completion(body)
         stream = bool(body.get("stream", False))
         comp = _Completion()
-        with self.lock:
+        with self._control():
             uid = self._next_uid
             self._next_uid += 1
             req = Request(uid=uid,
@@ -271,14 +295,14 @@ class ApiServer:
     def abort(self, uid: int, reason: str = "aborted_disconnect") -> None:
         """Cancel a request whose connection went away: release its
         slot/pages/uid immediately and stop tracking its queue."""
-        with self.lock:
+        with self._control():
             self._live.pop(uid, None)
             if self.engine.cancel(uid, reason=reason):
                 self._retire()
 
     def stats(self) -> Dict[str, Any]:
         """Engine counters + per-plan view + server gauges, all finite."""
-        with self.lock:
+        with self._control():
             eng = self.engine
             live = sum(t is not None for t in eng.sched.slots)
             queued = len(eng.sched.waiting)

@@ -87,6 +87,23 @@ def bucket_k(k: int, num_experts: int) -> int:
     return min(b, num_experts)
 
 
+def init_serving_params(key, cfg: ModelConfig):
+    """Random parameters made directly in the runner's per-layer split layout.
+
+    One jitted program writes each weight once, in its storage dtype, so
+    the device holds neither a grouped copy beside the split one (what
+    ``ModelRunner`` would otherwise regroup into) nor f32 init
+    temporaries -- what lets a published-width model fill most of a chip.
+    """
+    split = _split_cfg(cfg)
+    return jax.jit(lambda k: models.init_params(k, split))(key)
+
+
+def _is_split(stack, serve_cfg: ModelConfig) -> bool:
+    return (len(stack["groups"])
+            == len(blocks_mod.group_pattern(serve_cfg.pattern())))
+
+
 class ModelRunner:
     def __init__(self, cfg: ModelConfig, params, *, mesh=None,
                  opts: ModelOpts = DEFAULT_OPTS):
@@ -95,7 +112,9 @@ class ModelRunner:
         self.base_cfg = cfg
         serve_cfg = _split_cfg(cfg)
         serve_params = params
-        if "stack" in params:
+        # params already in the split layout (``init_serving_params``) are
+        # served as given: regrouping slices every layer into new buffers
+        if "stack" in params and not _is_split(params["stack"], serve_cfg):
             serve_params = dict(params)
             serve_params["stack"] = blocks_mod.regroup_stack(
                 params["stack"], cfg.pattern(), serve_cfg.pattern())
@@ -185,6 +204,16 @@ class ModelRunner:
         ([B, n_moe] i32) select a mixed-plan bucket graph instead of
         ``plan``'s graph; surplus routed slots are zero-weighted exactly.
         """
+        fn, args = self._decode_call(
+            tokens, pos, caches, block_tables, plan=plan,
+            use_kernel=use_kernel, kernel_blocks=kernel_blocks,
+            moe_decode=moe_decode, bucket=bucket, k_budgets=k_budgets)
+        return fn(*args)
+
+    def _decode_call(self, tokens, pos, caches, block_tables=None, *,
+                     plan: str = BASE_PLAN, use_kernel=None,
+                     kernel_blocks=None, moe_decode=None, bucket=None,
+                     k_budgets=None):
         head, cfg = self._resolve(plan, bucket)
         uk = self.opts.use_paged_kernel if use_kernel is None else bool(use_kernel)
         md = (self.opts.use_moe_decode_kernel if moe_decode is None
@@ -203,14 +232,23 @@ class ModelRunner:
                     opts=opts, kernel_blocks=kb, k_budgets=kbud))
         if bucket is not None:
             k_budgets = jnp.asarray(k_budgets, jnp.int32)
-        return self._jit[key](self.params, tokens, pos, caches, block_tables,
-                              k_budgets if bucket is not None else None)
+        return self._jit[key], (self.params, tokens, pos, caches,
+                                block_tables,
+                                k_budgets if bucket is not None else None)
 
     def chunk_prefill(self, tokens, positions, last_index, caches,
                       block_tables=None, *, plan: str = BASE_PLAN,
                       bucket: Optional[Tuple[int, ...]] = None,
                       k_budgets=None):
         """One ``[B, C]`` chunked-prefill step -> (logits [B,V], caches)."""
+        fn, args = self._chunk_call(tokens, positions, last_index, caches,
+                                    block_tables, plan=plan, bucket=bucket,
+                                    k_budgets=k_budgets)
+        return fn(*args)
+
+    def _chunk_call(self, tokens, positions, last_index, caches,
+                    block_tables=None, *, plan: str = BASE_PLAN, bucket=None,
+                    k_budgets=None):
         head, cfg = self._resolve(plan, bucket)
         key = (head, "chunk", int(tokens.shape[1]), self.opts.expert_dtype)
         if key not in self._jit:
@@ -220,9 +258,19 @@ class ModelRunner:
                     mesh=self.mesh, opts=self.opts, k_budgets=kbud))
         if bucket is not None:
             k_budgets = jnp.asarray(k_budgets, jnp.int32)
-        return self._jit[key](self.params, tokens, positions, last_index,
-                              caches, block_tables,
-                              k_budgets if bucket is not None else None)
+        return self._jit[key], (self.params, tokens, positions, last_index,
+                                caches, block_tables,
+                                k_budgets if bucket is not None else None)
+
+    def compiled_text(self, kind: str, *args, **kw) -> str:
+        """HLO text of the compiled graph ``decode``/``chunk_prefill``
+        would run for these arguments (same key, same specialization) --
+        what a caller inspects to see which kernels a step really calls
+        (``tpu_custom_call`` on TPU)."""
+        call = {"decode": self._decode_call,
+                "chunk_prefill": self._chunk_call}[kind]
+        fn, fn_args = call(*args, **kw)
+        return fn.lower(*fn_args).compile().as_text()
 
     def whole_prefill(self, tokens, positions, caches, *,
                       plan: str = BASE_PLAN):

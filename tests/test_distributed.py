@@ -40,11 +40,12 @@ class TestShardingRules:
     def test_param_specs_cover_all_archs(self):
         out = run_py("""
             import jax
+            from repro.launch.mesh import make_test_mesh
             from jax.sharding import PartitionSpec as P
             from repro.configs import ASSIGNED, get_config
             from repro import models
             from repro.sharding import rules
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_test_mesh((2, 4), ("data", "model"))
             for name in ASSIGNED:
                 cfg = get_config(name)
                 abs_p = models.abstract_params(cfg)
@@ -78,6 +79,7 @@ class TestMoEImplEquivalence:
     def test_dense_vs_ep_a2a_vs_ep_psum(self):
         out = run_py("""
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_test_mesh
             from repro.configs import get_config
             from repro import models
             from repro.models.moe import moe
@@ -88,7 +90,7 @@ class TestMoEImplEquivalence:
                 moe_capacity_factor=8.0)   # dropless: exact equivalence
             params = models.init_params(jax.random.PRNGKey(0), cfg)
             _, mp = next(iter_moe_layer_params(params, cfg))
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_test_mesh((2, 4), ("data", "model"))
             x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model))
 
             y0, a0 = moe(mp, cfg, x, 2, impl="dense")
@@ -110,6 +112,7 @@ class TestMoEImplEquivalence:
     def test_ep_a2a_grads_match_dense(self):
         out = run_py("""
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_test_mesh
             from repro.configs import get_config
             from repro import models
             from repro.models.moe import moe
@@ -120,7 +123,7 @@ class TestMoEImplEquivalence:
                 moe_capacity_factor=4.0)
             params = models.init_params(jax.random.PRNGKey(0), cfg)
             _, mp = next(iter_moe_layer_params(params, cfg))
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_test_mesh((2, 4), ("data", "model"))
             x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model))
 
             def loss(p, impl, m=None):
@@ -140,6 +143,7 @@ class TestMoEImplEquivalence:
         """Per-layer static k runs through the EP path with distinct shapes."""
         out = run_py("""
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_test_mesh
             from repro.configs import get_config
             from repro import models
             from repro.models.opts import ModelOpts
@@ -149,7 +153,7 @@ class TestMoEImplEquivalence:
                 moe_impl="ep_a2a")
             n = cfg.num_moe_layers
             cfg = cfg.with_lexi_plan(tuple(1 + (i % 4) for i in range(n)))
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_test_mesh((2, 4), ("data", "model"))
             params = models.init_params(jax.random.PRNGKey(0), cfg)
             batch = models.make_train_batch(cfg, jax.random.PRNGKey(1), 4, 32)
             loss, _ = jax.jit(lambda p, b: models.loss_fn(p, cfg, b,
@@ -166,13 +170,14 @@ class TestSeqShardDecode:
         across two steps (cache written into the sharded layout)."""
         out = run_py("""
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_test_mesh
             from repro.configs import get_config
             from repro import models
             from repro.models.opts import ModelOpts
             cfg = get_config('qwen3-32b').reduced().with_(
                 dtype='float32', num_layers=2, num_kv_heads=2)
             params = models.init_params(jax.random.PRNGKey(0), cfg)
-            mesh = jax.make_mesh((2, 4), ('data', 'model'))
+            mesh = make_test_mesh((2, 4), ('data', 'model'))
             B, plen, S = 4, 16, 32
             tokens = jax.random.randint(jax.random.PRNGKey(1), (B, plen), 0,
                                         cfg.vocab_size)
@@ -208,16 +213,17 @@ class TestElasticRestore:
         ck = str(tmp_path / "ck")
         out = run_py(f"""
             import jax, jax.numpy as jnp, numpy as np
+            from repro.launch.mesh import make_test_mesh
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.checkpoint import CheckpointManager
 
-            mesh_a = jax.make_mesh((2, 4), ("data", "model"))
+            mesh_a = make_test_mesh((2, 4), ("data", "model"))
             w = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
             sharded = jax.device_put(w, NamedSharding(mesh_a, P("data", "model")))
             mgr = CheckpointManager({ck!r})
             mgr.save(7, {{"w": sharded}})
 
-            mesh_b = jax.make_mesh((4, 2), ("data", "model"))
+            mesh_b = make_test_mesh((4, 2), ("data", "model"))
             target_sh = {{"w": NamedSharding(mesh_b, P("model", "data"))}}
             restored, meta = mgr.restore({{"w": w}}, shardings=target_sh)
             np.testing.assert_array_equal(np.asarray(restored["w"]),
@@ -292,6 +298,7 @@ class TestDryrunPlumbing:
         """The EP dispatch all-to-all must appear in parsed collectives."""
         out = run_py("""
             import jax, jax.numpy as jnp
+            from repro.launch.mesh import make_test_mesh
             from repro.configs import get_config
             from repro import models
             from repro.models.moe import moe
@@ -301,7 +308,7 @@ class TestDryrunPlumbing:
                 num_experts=8, moe_top_k=2, dtype="float32")
             params = models.init_params(jax.random.PRNGKey(0), cfg)
             _, mp = next(iter_moe_layer_params(params, cfg))
-            mesh = jax.make_mesh((2, 4), ("data", "model"))
+            mesh = make_test_mesh((2, 4), ("data", "model"))
             x = jax.ShapeDtypeStruct((16, 16, cfg.d_model), jnp.float32)
             c = jax.jit(lambda p, xx: moe(p, cfg, xx, 2, impl="ep_a2a",
                                           mesh=mesh)).lower(mp, x).compile()
@@ -314,9 +321,10 @@ class TestDryrunPlumbing:
     def test_hlo_collective_parser(self):
         out = run_py("""
             import jax, jax.numpy as jnp
+            from repro.launch.mesh import make_test_mesh
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.analysis.hlo import collective_stats
-            mesh = jax.make_mesh((8,), ("x",))
+            mesh = make_test_mesh((8,), ("x",))
             def f(a):
                 return jax.lax.with_sharding_constraint(
                     a.sum(0, keepdims=True), NamedSharding(mesh, P()))

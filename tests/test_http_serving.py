@@ -21,6 +21,7 @@ import http.client
 import json
 import math
 import socket
+import sys
 import threading
 import time
 
@@ -314,6 +315,44 @@ class TestHttpApi:
                              and eng.kv.free_pages() == free0)
                 time.sleep(0.02)
             assert clean, "disconnect did not release pages/uid/records"
+
+    def test_submit_not_starved_by_a_busy_pump(self, setup):
+        """A submission queued for the engine lock goes in before the
+        pump's next step: a pump running back-to-back steps would
+        otherwise retake the lock first, and a new request would wait
+        until the engine went idle."""
+        cfg, params = setup
+        eng = Engine(cfg, params, max_batch=2, max_len=4096)
+        steps = [0]
+        step = eng.step
+
+        def counted_step():
+            steps[0] += 1
+            return step()
+
+        eng.step = counted_step
+        switch = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            with ApiServer(eng) as api:
+                api.submit({"prompt": [3, 1, 4], "max_new_tokens": 4000})
+                deadline = time.monotonic() + 60
+                while steps[0] < 5 and time.monotonic() < deadline:
+                    time.sleep(0.01)
+                waited = []
+                for _ in range(10):
+                    before = steps[0]
+                    api.submit({"prompt": [2, 7, 1], "max_new_tokens": 1})
+                    waited.append(steps[0] - before)
+                    time.sleep(0.005)
+                with api.lock:
+                    busy = not eng.sched.done()
+        finally:
+            sys.setswitchinterval(switch)
+        assert busy, ("the long request finished before the check", waited)
+        # the step under way when the submission queued, and at most one
+        # that began before it was counted as queued
+        assert max(waited) <= 2, waited
 
     def test_stats_finite_and_health_midflight(self, setup):
         """/v1/stats must be valid strict JSON (no NaN/Infinity) at any

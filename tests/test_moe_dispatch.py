@@ -80,6 +80,14 @@ class TestGmmEqualsDense:
         with pytest.raises(ValueError, match="unknown moe impl"):
             moe(mp, cfg, x, 2, impl="nope")
 
+    @pytest.mark.parametrize("impl", ["ep_a2a", "ep_psum"])
+    def test_expert_parallel_without_mesh_raises(self, impl):
+        """No silent substitution of the capacity-dropping dense impl."""
+        cfg, mp = _layer(8, 2)
+        x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, cfg.d_model))
+        with pytest.raises(ValueError, match="mesh"):
+            moe(mp, cfg, x, 2, impl=impl)
+
     def test_gmm_grads_match_dense(self):
         cfg, mp = _layer(8, 2)
         x = jax.random.normal(jax.random.PRNGKey(3), (24, cfg.d_model))

@@ -1,0 +1,97 @@
+"""Ahead-of-time compiles of the serving kernels for a described TPU v5e.
+
+Interpret mode (every other kernel test) cannot see what the chip's
+compiler refuses: blocks whose last two dims break the (8, 128) tiling,
+or tiles that overflow the scoped VMEM.  These tests compile each kernel
+of the serving path at OLMoE-1B-7B's published widths -- d_model 2048, 64
+experts, moe_d_ff 1024, top-8, 16 x 128 KV heads, page 16, a decode batch
+of 8 -- for one chip of a described ``v5e:2x2`` topology, with no chip
+attached, and check that Mosaic kernels (``tpu_custom_call``) come out.
+
+The topology is described inside a fixture (never at import), so only the
+worker that runs this file loads the TPU compiler.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_decode_paged import flash_decode_paged_pallas
+from repro.kernels.moe_decode import moe_decode_pallas, \
+    moe_decode_quant_pallas
+from repro.kernels.moe_gmm import moe_gmm_pallas, moe_gmm_quant_pallas
+
+D, E, F, K, B = 2048, 64, 1024, 8, 8         # OLMoE-1B-7B widths
+HKV, HD, PAGE = 16, 128, 16
+POOL_PAGES = B * 2048 // PAGE + 1           # 8 x 2048 tokens + trash page
+BLOCK_M, N_TILES = 128, 16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but can never be read back here; keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+            for s, dt in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def test_moe_gmm_compiles(one_chip):
+    _compile(lambda xs, w1, w2, te, tv: moe_gmm_pallas(
+        xs, w1, w2, te, tv, block_m=BLOCK_M), one_chip,
+        ((N_TILES * BLOCK_M, D), jnp.bfloat16), ((E, D, 2 * F), jnp.bfloat16),
+        ((E, F, D), jnp.bfloat16), ((N_TILES,), jnp.int32),
+        ((N_TILES,), jnp.int32))
+
+
+def test_moe_decode_compiles(one_chip):
+    _compile(moe_decode_pallas, one_chip,
+             ((B, D), jnp.bfloat16), ((E, D, 2 * F), jnp.bfloat16),
+             ((E, F, D), jnp.bfloat16), ((B, K), jnp.int32),
+             ((B, K), jnp.float32))
+
+
+def test_flash_decode_paged_compiles(one_chip):
+    _compile(flash_decode_paged_pallas, one_chip,
+             ((B, HKV, HD), jnp.bfloat16),
+             ((POOL_PAGES, PAGE, HKV, HD), jnp.bfloat16),
+             ((POOL_PAGES, PAGE, HKV, HD), jnp.bfloat16),
+             ((POOL_PAGES, PAGE), jnp.int32),
+             ((B, 2048 // PAGE), jnp.int32), ((B,), jnp.int32))
+
+
+@pytest.mark.parametrize("dtype", ["int8", "int4"])
+def test_quantized_expert_kernels_compile(one_chip, dtype):
+    dp = D // 2 if dtype == "int4" else D
+    tiles = (((E, dp, 2 * F), jnp.int8), ((E, F, dp), jnp.int8),
+             ((E, 2, F), jnp.float32), ((E, F), jnp.float32))
+    _compile(lambda x, a, b, c, d, i, w: moe_decode_quant_pallas(
+        x, a, b, c, d, i, w, dtype=dtype), one_chip,
+        ((B, D), jnp.bfloat16), *tiles, ((B, K), jnp.int32),
+        ((B, K), jnp.float32))
+    _compile(lambda xs, a, b, c, d, te, tv: moe_gmm_quant_pallas(
+        xs, a, b, c, d, te, tv, dtype=dtype, block_m=BLOCK_M), one_chip,
+        ((N_TILES * BLOCK_M, D), jnp.bfloat16), *tiles,
+        ((N_TILES,), jnp.int32), ((N_TILES,), jnp.int32))
