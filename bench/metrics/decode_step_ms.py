@@ -1,0 +1,7 @@
+"""Mean device time of one decode step program in the traced window, ms."""
+
+from bench import measure
+
+
+def read(run):
+    return measure.mean_step_ms(run, "decode")

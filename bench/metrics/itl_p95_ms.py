@@ -1,0 +1,9 @@
+"""95th percentile of every gap between consecutive streamed tokens of a
+request in the window, in ms."""
+
+from bench import measure
+
+
+def read(run):
+    p = measure.pct(measure.token_gaps(run), 95)
+    return None if p is None else 1e3 * p
