@@ -159,6 +159,7 @@ def flash_decode_paged_pallas(q, kp, vp, posp, block_tables, cur_pos, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, g, hkv, hd), q.dtype),
         interpret=interpret,
+        name="flash_decode_paged",
     )(block_tables.astype(jnp.int32), cur_pos.astype(jnp.int32), qg, kp, vp,
       pos4)
     return out.transpose(0, 2, 1, 3).reshape(b, hq, hd)
@@ -248,5 +249,6 @@ def flash_decode_paged_mla_pallas(q_lat, q_rope, ckvp, kropep, posp,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, r), jnp.float32),
         interpret=interpret,
+        name="flash_decode_paged_mla",
     )(block_tables.astype(jnp.int32), cur_pos.astype(jnp.int32), q_lat,
       q_rope, ckvp, kropep, posp.reshape(n, 1, p))

@@ -141,6 +141,7 @@ def moe_decode_pallas(x, w1, w2, idx, weights, *, block_f: int = 256,
         grid_spec=decode_grid_spec(b, k, d, d, bf, n_f),
         out_shape=jax.ShapeDtypeStruct((b, d), x.dtype),
         interpret=interpret,
+        name="moe_decode",
     )(idx.astype(jnp.int32), weights.astype(jnp.float32), x, w1, w1, w2)
 
 
@@ -267,6 +268,7 @@ def moe_decode_quant_pallas(x, w1q, w2q, s1, s2, idx, weights, *,
             extra_specs=lambda tile_of: quant_scale_specs(bf, tile_of)),
         out_shape=jax.ShapeDtypeStruct((b, d), x.dtype),
         interpret=interpret,
+        name="moe_decode_quant",
     )(idx.astype(jnp.int32), weights.astype(jnp.float32), x, w1q, w1q, w2q,
       s1v, s1v, s2v)
 

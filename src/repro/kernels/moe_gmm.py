@@ -154,6 +154,7 @@ def moe_gmm_pallas(xs, w1, w2, tile_expert, tile_valid, *, block_m: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, d), xs.dtype),
         interpret=interpret,
+        name="moe_gmm",
     )(tile_expert, tile_valid, xs, w1, w1, w2)
 
 
@@ -308,4 +309,5 @@ def moe_gmm_quant_pallas(xs, w1q, w2q, s1, s2, tile_expert, tile_valid, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, d), xs.dtype),
         interpret=interpret,
+        name="moe_gmm_quant",
     )(tile_expert, tile_valid, xs, w1q, w1q, w2q, s1v, s1v, s2v)

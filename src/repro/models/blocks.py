@@ -234,18 +234,23 @@ def apply_stack(
     block_tables=None,
     kernel_blocks: Optional[int] = None,
     k_budgets=None,
+    count_routed: bool = False,
 ):
-    """Run all layer groups.  Returns (x, new_caches, total_aux).
+    """Run all layer groups.  Returns (x, new_caches, total_aux), and with
+    ``count_routed`` a fourth value: ``[n_moe]`` i32, the distinct experts
+    each MoE layer routed the live rows (``positions >= 0``) to, within
+    each row's budget (``moe.routed_experts``).
 
     ``k_budgets`` [B, n_moe] i32 gives each batch row a per-MoE-layer
     active-expert cap below the pattern's static per-layer top-k
     (per-request LExI plans, DESIGN.md §10).  Only single-layer groups can
-    carry budgets -- serving uses per-layer split patterns
-    (``BlockSpec.split_id``), which guarantee that.
+    carry budgets or count routed experts -- serving uses per-layer split
+    patterns (``BlockSpec.split_id``), which guarantee that.
     """
     groups = group_pattern(cfg.pattern())
     total_aux = jnp.zeros((), jnp.float32)
     new_caches = []
+    routed = []
     use_cache = caches is not None
     lookahead = opts.router_lookahead and mode == "decode"
     moe_layer_i = 0  # running index into k_budgets' layer axis
@@ -262,11 +267,14 @@ def apply_stack(
             gparams = params["shared_attn"]
         gl = lookahead and g.spec.kind != "mamba"
         g_budget = None
+        if g.spec.kind == "attn_moe" and g.count != 1:
+            for what, on in (("k_budgets", k_budgets is not None),
+                             ("count_routed", count_routed)):
+                if on:
+                    raise ValueError(
+                        f"{what} requires single-layer MoE groups; use a "
+                        "per-layer split pattern (BlockSpec.split_id)")
         if k_budgets is not None and g.spec.kind == "attn_moe":
-            if g.count != 1:
-                raise ValueError(
-                    "k_budgets requires single-layer MoE groups; use a "
-                    "per-layer split pattern (BlockSpec.split_id)")
             g_budget = k_budgets[:, moe_layer_i]
         if g.spec.kind == "attn_moe":
             moe_layer_i += g.count
@@ -287,6 +295,9 @@ def apply_stack(
                                        h2_prev if gl else None)
             if gl:
                 h2_prev = h2
+            if count_routed and g.spec.kind == "attn_moe":
+                routed.append(_routed_count(gparams["moe"], cfg, g.spec,
+                                            h2, positions, g_budget))
             new_caches.append(nc)
             total_aux = total_aux + aux
         elif use_cache:
@@ -357,7 +368,22 @@ def apply_stack(
             new_caches.append(None)
             total_aux = total_aux + jnp.sum(auxs)
 
-    return x, (new_caches if use_cache else None), total_aux
+    out = (x, (new_caches if use_cache else None), total_aux)
+    if count_routed:
+        out += (jnp.stack(routed) if routed
+                else jnp.zeros((0,), jnp.int32),)
+    return out
+
+
+def _routed_count(params, cfg: ModelConfig, spec: BlockSpec, h2, positions,
+                  k_budget):
+    """Distinct experts one MoE layer routed the live rows to (i32)."""
+    b, s, d = h2.shape
+    live = jnp.broadcast_to((positions >= 0).reshape(b, -1), (b, s))
+    kb = (None if k_budget is None else jnp.broadcast_to(
+        k_budget.astype(jnp.int32)[:, None], (b, s)).reshape(-1))
+    return moe_mod.routed_experts(params, cfg, h2.reshape(b * s, d),
+                                  spec.moe_top_k, live.reshape(-1), kb)
 
 
 def ungroup_stack(stack_params: Dict, pattern: Tuple[BlockSpec, ...]):

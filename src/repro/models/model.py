@@ -4,6 +4,7 @@
     loss_fn(params, cfg, batch, ...)       -> (loss, metrics)   [train]
     prefill_fn(params, cfg, batch, caches) -> (logits, caches)
     decode_fn(params, cfg, tokens, pos, caches) -> (logits, caches)
+        (count_routed=True adds the per-MoE-layer distinct-experts count)
     init_caches(cfg, batch, max_len)       -> cache pytree
 
 The dry-run, trainer, server and benchmarks all go through this module so an
@@ -75,14 +76,16 @@ def prefill_fn(params, cfg: ModelConfig, batch, caches, *, mesh=None,
 
 def decode_fn(params, cfg: ModelConfig, tokens, pos, caches, *, mesh=None,
               opts: ModelOpts = DEFAULT_OPTS, block_tables=None,
-              kernel_blocks=None, k_budgets=None):
+              kernel_blocks=None, k_budgets=None, count_routed: bool = False):
     if cfg.is_encoder_decoder:
-        return encdec_mod.encdec_decode_step(params, cfg, tokens, pos, caches,
-                                             mesh=mesh, opts=opts)
+        out = encdec_mod.encdec_decode_step(params, cfg, tokens, pos, caches,
+                                            mesh=mesh, opts=opts)
+        # the decoder stack has no MoE layers to count
+        return out + ((jnp.zeros((0,), jnp.int32),) if count_routed else ())
     return tf_mod.decode_step(params, cfg, tokens, pos, caches,
                               mesh=mesh, opts=opts, block_tables=block_tables,
                               kernel_blocks=kernel_blocks,
-                              k_budgets=k_budgets)
+                              k_budgets=k_budgets, count_routed=count_routed)
 
 
 def chunk_prefill_fn(params, cfg: ModelConfig, tokens, positions, caches, *,

@@ -66,6 +66,7 @@ from repro.models.moe.router import (  # noqa: F401
     capacity,
     route,
     route_lookahead,
+    routed_experts,
 )
 
 # back-compat alias for callers of the pre-package private helper

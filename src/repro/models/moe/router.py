@@ -55,6 +55,28 @@ def route(params: Dict, cfg: ModelConfig, x2d, top_k: int, k_budget=None):
     return weights, idx, aux
 
 
+def routed_experts(params: Dict, cfg: ModelConfig, x2d, top_k: int, live,
+                   k_budget=None):
+    """Distinct experts the live tokens route to -> i32 scalar.
+
+    ``live`` [T] bool marks the tokens that count (a decode step's
+    occupied slots); ``k_budget`` [T] i32, when given, counts only each
+    token's first ``k_budget`` routed slots, the ones ``route`` leaves a
+    weight.  The ids are ``route``'s own: the same scoring and top-k on
+    the same input, which XLA's common-subexpression pass merges with the
+    MoE layer's call.  The count is a one-hot against the expert axis,
+    masked and any-reduced over tokens and slots: no scatter.
+    """
+    _, idx, _ = route(params, cfg, x2d, top_k)                   # [T, k]
+    use = jnp.broadcast_to(live[:, None], idx.shape)
+    if k_budget is not None:
+        use = use & (jnp.arange(top_k, dtype=jnp.int32)[None, :]
+                     < k_budget[:, None])
+    hit = (idx[..., None] == jnp.arange(cfg.num_experts, dtype=idx.dtype)
+           ) & use[..., None]                                    # [T, k, E]
+    return jnp.sum(jnp.any(hit, axis=(0, 1)), dtype=jnp.int32)
+
+
 def route_lookahead(params: Dict, cfg: ModelConfig, x2d, top_k: int):
     """Predict this layer's top-k expert ids from the *previous* layer's
     pre-FFN hidden state -> pred_idx [T, k] i32.

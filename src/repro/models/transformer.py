@@ -83,10 +83,13 @@ def forward(
     block_tables=None,
     kernel_blocks=None,
     k_budgets=None,
+    count_routed: bool = False,
 ):
     """tokens [B,S]; positions [B,S] (train/prefill/chunk) or [B] (decode).
 
-    Returns (hidden [B,S,D], new_caches, aux_loss).  ``k_budgets``
+    Returns (hidden [B,S,D], new_caches, aux_loss), and with
+    ``count_routed`` the ``[n_moe]`` distinct-experts count of
+    ``blocks.apply_stack``.  ``k_budgets``
     [B, n_moe] i32 caps per-row active experts below the pattern's static
     per-layer top-k (per-request LExI plans; DESIGN.md §10).
     """
@@ -94,11 +97,11 @@ def forward(
     if prefix_embeds is not None:
         pre = prefix_embeds.astype(x.dtype) @ params["prefix_proj"]
         x = jnp.concatenate([pre, x], axis=1)
-    x, new_caches, aux = blocks_mod.apply_stack(
+    return blocks_mod.apply_stack(
         params["stack"], cfg, x, positions, mode=mode, caches=caches,
         mesh=mesh, opts=opts, block_tables=block_tables,
-        kernel_blocks=kernel_blocks, k_budgets=k_budgets)
-    return x, new_caches, aux
+        kernel_blocks=kernel_blocks, k_budgets=k_budgets,
+        count_routed=count_routed)
 
 
 # --------------------------------------------------------------------------- #
@@ -226,15 +229,18 @@ def decode_step(
     block_tables=None,
     kernel_blocks=None,
     k_budgets=None,
+    count_routed: bool = False,
 ):
-    """One decode step.  Returns (logits [B,V] f32, updated caches).
+    """One decode step.  Returns (logits [B,V] f32, updated caches), and
+    with ``count_routed`` a third value: ``[n_moe]`` i32, the distinct
+    experts each MoE layer routed the live slots (``pos >= 0``) to.
 
     ``kernel_blocks`` statically bounds the paged-kernel table walk to the
     live-page bucket (ignored by the gather path)."""
-    hidden, caches, _ = forward(params, cfg, tokens[:, None], pos, mode="decode",
-                                caches=caches, mesh=mesh, opts=opts,
-                                block_tables=block_tables,
-                                kernel_blocks=kernel_blocks,
-                                k_budgets=k_budgets)
+    hidden, caches, _, *routed = forward(
+        params, cfg, tokens[:, None], pos, mode="decode", caches=caches,
+        mesh=mesh, opts=opts, block_tables=block_tables,
+        kernel_blocks=kernel_blocks, k_budgets=k_budgets,
+        count_routed=count_routed)
     logits = lm_logits(params, cfg, hidden)[:, 0]
-    return logits, caches
+    return (logits, caches, *routed)

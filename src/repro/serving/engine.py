@@ -75,6 +75,7 @@ from repro.serving.runner import BASE_PLAN, ModelRunner
 from repro.serving.sampling import sample_per_slot
 from repro.serving.scheduler import DECODE, DONE, PREFILL, Scheduler, \
     Tracked, duplicate_uid_error
+from repro.serving.trace import CPU_KEY, PHASES, WALL_PREFIX, phase
 
 _CHUNKABLE_KINDS = ("attn_mlp", "attn_moe", "shared_attn")
 
@@ -83,6 +84,11 @@ _CHUNKABLE_KINDS = ("attn_mlp", "attn_moe", "shared_attn")
 #: already decoding, so admitting a newcomer does not just preempt it
 #: right back out (admit -> evict -> recompute churn)
 ADMISSION_POLICIES = ("headroom", "watermark", "lookahead", "greedy")
+
+
+def _plan_label(plan: str, bucket) -> str:
+    """A step's plan for its span: the plan's name, or its k bucket."""
+    return plan if bucket is None else "bucket:" + "-".join(map(str, bucket))
 
 
 def _supports_paging(cfg: ModelConfig) -> bool:
@@ -264,20 +270,32 @@ class Engine:
         self.slot_budget = np.zeros(max_batch, np.int32)
         self.slot_temp = np.zeros(max_batch, np.float32)
         self.slot_topk = np.zeros(max_batch, np.int32)      # 0 = no top-k cap
+        #: one distinct-experts counter per MoE layer, in plan order
+        self._routed_keys = tuple(
+            f"experts_routed:l{i}"
+            for i in range(len(self.runner.plan_ks[BASE_PLAN])))
         self.stats: Dict[str, float] = self._fresh_stats()
 
-    @staticmethod
-    def _fresh_stats() -> Dict[str, float]:
+    def _fresh_stats(self) -> Dict[str, float]:
         # prefill_tokens counts each prompt position once (useful work);
         # positions re-prefilled when a preempted request resumes land in
         # recompute_tokens instead, so throughput() reflects useful tokens
         # prefix_hit_tokens counts positions served from cached pages
         # (never computed this admission); prefill_tokens keeps counting
-        # only positions actually computed, so throughput() stays honest
-        return {"prefill_tokens": 0, "decode_tokens": 0,
-                "recompute_tokens": 0, "steps": 0, "preemptions": 0,
-                "live_peak": 0, "prefix_hit_tokens": 0, "cow_copies": 0,
-                "plan_degradations": 0, "mixed_plan_steps": 0}
+        # only positions actually computed, so throughput() stays honest.
+        # steps counts decode steps, chunk_steps chunked-prefill steps,
+        # iterations calls of step().  host_s:<phase> is each phase's wall
+        # time and host_cpu_s the pump's CPU time outside the waits
+        # (serving/trace.py); experts_routed:l<i> sums, over decode steps,
+        # the distinct experts MoE layer i routed the live slots to.
+        out = {"prefill_tokens": 0, "decode_tokens": 0,
+               "recompute_tokens": 0, "steps": 0, "preemptions": 0,
+               "live_peak": 0, "prefix_hit_tokens": 0, "cow_copies": 0,
+               "plan_degradations": 0, "mixed_plan_steps": 0,
+               "chunk_steps": 0, "iterations": 0, CPU_KEY: 0.0}
+        out.update({WALL_PREFIX + p: 0.0 for p in PHASES})
+        out.update({k: 0 for k in self._routed_keys})
+        return out
 
     # ------------------------------------------------------------------ #
     # Plans
@@ -695,51 +713,70 @@ class Engine:
         positions/tokens start at the first uncached position with no
         graph change -- positions are explicit arrays already.
         """
-        c = self.prefill_chunk
-        tokens = np.zeros((self.max_batch, c), np.int32)
-        positions = np.full((self.max_batch, c), -1, np.int32)
-        last_idx = np.zeros(self.max_batch, np.int32)
-        sampling: List[Tracked] = []
-        for t in prefilling:
-            n = min(c, t.fill_len - t.consumed)
-            tokens[t.slot, :n] = t.fill[t.consumed:t.consumed + n]
-            positions[t.slot, :n] = np.arange(t.consumed, t.consumed + n)
-            self.kv.assert_private(t.slot, t.consumed, t.consumed + n)
-            t.consumed += n
-            if t.resuming:
-                self.stats["recompute_tokens"] += n
-                t.result.recompute_tokens += n
-            else:
-                # a victim evicted mid-prefill re-runs positions already
-                # charged as useful work: only the advance past its
-                # prefill high-water mark counts as fresh
-                fresh = min(n, max(0, t.consumed - t.prefill_done))
-                self.stats["prefill_tokens"] += fresh
-                self.stats["recompute_tokens"] += n - fresh
-                t.result.recompute_tokens += n - fresh
-                t.prefill_done = max(t.prefill_done, t.consumed)
-            if t.consumed == t.fill_len:
+        st = self.stats
+        with phase(st, "engine.chunk.prepare"):
+            c = self.prefill_chunk
+            tokens = np.zeros((self.max_batch, c), np.int32)
+            positions = np.full((self.max_batch, c), -1, np.int32)
+            last_idx = np.zeros(self.max_batch, np.int32)
+            sampling: List[Tracked] = []
+            n_tok = 0
+            for t in prefilling:
+                n = min(c, t.fill_len - t.consumed)
+                n_tok += n
+                tokens[t.slot, :n] = t.fill[t.consumed:t.consumed + n]
+                positions[t.slot, :n] = np.arange(t.consumed, t.consumed + n)
+                self.kv.assert_private(t.slot, t.consumed, t.consumed + n)
+                t.consumed += n
                 if t.resuming:
-                    t.state = DECODE
-                    self.slot_pos[t.slot] = t.fill_len
-                    self.slot_last[t.slot] = t.result.tokens[-1]
+                    st["recompute_tokens"] += n
+                    t.result.recompute_tokens += n
                 else:
-                    last_idx[t.slot] = n - 1
-                    sampling.append(t)
-        plan, bucket, budgets = self._plan_batch(prefilling)
-        logits, self.kv.caches = self.runner.chunk_prefill(
-            jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(last_idx), self.kv.caches, self.kv.block_tables(),
-            plan=plan, bucket=bucket, k_budgets=budgets)
-        for t in prefilling:    # chunk writes are committed: index them
-            self._register_pages(t, t.consumed)
+                    # a victim evicted mid-prefill re-runs positions
+                    # already charged as useful work: only the advance
+                    # past its prefill high-water mark counts as fresh
+                    fresh = min(n, max(0, t.consumed - t.prefill_done))
+                    st["prefill_tokens"] += fresh
+                    st["recompute_tokens"] += n - fresh
+                    t.result.recompute_tokens += n - fresh
+                    t.prefill_done = max(t.prefill_done, t.consumed)
+                if t.consumed == t.fill_len:
+                    if t.resuming:
+                        t.state = DECODE
+                        self.slot_pos[t.slot] = t.fill_len
+                        self.slot_last[t.slot] = t.result.tokens[-1]
+                    else:
+                        last_idx[t.slot] = n - 1
+                        sampling.append(t)
+            plan, bucket, budgets = self._plan_batch(prefilling)
+            args = (jnp.asarray(tokens), jnp.asarray(positions),
+                    jnp.asarray(last_idx), self.kv.caches,
+                    self.kv.block_tables())
+        with phase(st, "engine.chunk.dispatch", step=int(st["chunk_steps"]),
+                   plan=_plan_label(plan, bucket), tokens=n_tok):
+            logits, self.kv.caches = self.runner.chunk_prefill(
+                *args, plan=plan, bucket=bucket, k_budgets=budgets)
+            del args        # the last reference to the previous pool
+            st["chunk_steps"] += 1
+        with phase(st, "engine.chunk.sample"):
+            for t in prefilling:    # chunk writes are committed: index them
+                self._register_pages(t, t.consumed)
+            if sampling:
+                self.key, sub = jax.random.split(self.key)
+                nxt = sample_per_slot(logits, sub,
+                                      jnp.asarray(self.slot_temp),
+                                      self._topks())
+            else:
+                # freeing device arrays can hand the GIL to the connection
+                # threads: do it in a phase, not on the way out
+                del logits
         if sampling:
-            self.key, sub = jax.random.split(self.key)
-            nxt = np.asarray(sample_per_slot(logits, sub,
-                                             jnp.asarray(self.slot_temp),
-                                             self._topks()))
-            for t in sampling:
-                self._first_token(t, int(nxt[t.slot]))
+            with phase(st, "engine.chunk.wait"):
+                nxt = np.asarray(nxt)
+            with phase(st, "engine.chunk.commit"):
+                del logits, sub
+                for t in sampling:
+                    self._first_token(t, int(nxt[t.slot]))
 
     def _preempt(self, t: Tracked) -> None:
         """Evict a live request: pages back to the pool, request re-queued
@@ -777,54 +814,72 @@ class Engine:
         return self.sched.in_state(DECODE)
 
     def _decode_step(self, decoding: List[Tracked]) -> None:
-        if self.ondemand:
-            decoding = self._grow_or_preempt(decoding)
-            if not decoding:
-                return
-        tokens = np.zeros(self.max_batch, np.int32)
-        pos = np.full(self.max_batch, -1, np.int32)
-        for t in decoding:
-            tokens[t.slot] = self.slot_last[t.slot]
-            pos[t.slot] = self.slot_pos[t.slot]
-            # decode never writes into a shared (rc>1) page: the write
-            # position is past the shared prefix by construction (COW
-            # copied the boundary page at admission)
-            self.kv.assert_private(t.slot, int(pos[t.slot]),
-                                   int(pos[t.slot]) + 1)
-        kernel_blocks = (self.kv.live_blocks(pos)
-                         if self.use_kernel and self.kv.layout == "paged"
-                         else None)
-        plan, bucket, budgets = self._plan_batch(decoding)
-        logits, self.kv.caches = self.runner.decode(
-            jnp.asarray(tokens), jnp.asarray(pos), self.kv.caches,
-            self.kv.block_tables(), plan=plan,
-            use_kernel=self.use_kernel, kernel_blocks=kernel_blocks,
-            moe_decode=self.use_moe_decode,
-            bucket=bucket, k_budgets=budgets)
-        self.key, sub = jax.random.split(self.key)
-        nxt = np.asarray(sample_per_slot(logits, sub,
-                                         jnp.asarray(self.slot_temp),
-                                         self._topks()))
-        self.stats["steps"] += 1
-        for t in decoding:
-            self.slot_pos[t.slot] += 1
-            tok = int(nxt[t.slot])
-            self.sched.record_token(t, tok)
-            self.slot_last[t.slot] = tok
-            self.slot_budget[t.slot] -= 1
-            self.stats["decode_tokens"] += 1
-            k = f"plan_decode_tokens:{t.served_plan}"
-            self.stats[k] = self.stats.get(k, 0) + 1
-            # register before any finish: a finishing request's pages park
-            # in the LRU (content intact) instead of the free list, so its
-            # prefix stays reusable after release
-            self._register_pages(t, int(self.slot_pos[t.slot]))
-            eos = self._eos_of(t)
-            done_eos = eos is not None and tok == eos
-            done_len = (self.slot_budget[t.slot] <= 0
-                        or self.slot_pos[t.slot] >= self.max_len - 1)
-            if done_eos or done_len:
-                self._finish(t, "eos" if done_eos else "length")
+        st = self.stats
+        with phase(st, "engine.decode.prepare"):
+            if self.ondemand:
+                decoding = self._grow_or_preempt(decoding)
+            if decoding:
+                tokens = np.zeros(self.max_batch, np.int32)
+                pos = np.full(self.max_batch, -1, np.int32)
+                for t in decoding:
+                    tokens[t.slot] = self.slot_last[t.slot]
+                    pos[t.slot] = self.slot_pos[t.slot]
+                    # decode never writes into a shared (rc>1) page: the
+                    # write position is past the shared prefix by
+                    # construction (COW copied the boundary page at
+                    # admission)
+                    self.kv.assert_private(t.slot, int(pos[t.slot]),
+                                           int(pos[t.slot]) + 1)
+                kernel_blocks = (self.kv.live_blocks(pos)
+                                 if self.use_kernel
+                                 and self.kv.layout == "paged" else None)
+                plan, bucket, budgets = self._plan_batch(decoding)
+                kv_tokens = int(np.sum(pos[pos >= 0] + 1))
+                args = (jnp.asarray(tokens), jnp.asarray(pos),
+                        self.kv.caches, self.kv.block_tables())
+        if not decoding:
+            return
+        with phase(st, "engine.decode.dispatch", step=int(st["steps"]),
+                   plan=_plan_label(plan, bucket), live=len(decoding),
+                   kv_tokens=kv_tokens):
+            logits, self.kv.caches = self.runner.decode(
+                *args, plan=plan, use_kernel=self.use_kernel,
+                kernel_blocks=kernel_blocks, moe_decode=self.use_moe_decode,
+                bucket=bucket, k_budgets=budgets)
+            del args        # the last reference to the previous pool
+        with phase(st, "engine.decode.sample"):
+            self.key, sub = jax.random.split(self.key)
+            nxt = sample_per_slot(logits, sub, jnp.asarray(self.slot_temp),
+                                  self._topks())
+        with phase(st, "engine.decode.wait"):
+            # one fetch: the step's tokens and its routed-experts count
+            nxt, routed = jax.device_get((nxt, self.runner.routed))
+        with phase(st, "engine.decode.commit"):
+            # freeing device arrays can hand the GIL to the connection
+            # threads: do it here, not on the way out of the method
+            del logits, sub
+            st["steps"] += 1
+            for key, n in zip(self._routed_keys, routed):
+                st[key] += int(n)
+            for t in decoding:
+                self.slot_pos[t.slot] += 1
+                tok = int(nxt[t.slot])
+                self.sched.record_token(t, tok)
+                self.slot_last[t.slot] = tok
+                self.slot_budget[t.slot] -= 1
+                st["decode_tokens"] += 1
+                k = f"plan_decode_tokens:{t.served_plan}"
+                st[k] = st.get(k, 0) + 1
+                # register before any finish: a finishing request's pages
+                # park in the LRU (content intact) instead of the free
+                # list, so its prefix stays reusable after release
+                self._register_pages(t, int(self.slot_pos[t.slot]))
+                eos = self._eos_of(t)
+                done_eos = eos is not None and tok == eos
+                done_len = (self.slot_budget[t.slot] <= 0
+                            or self.slot_pos[t.slot] >= self.max_len - 1)
+                if done_eos or done_len:
+                    self._finish(t, "eos" if done_eos else "length")
 
     def _abort(self, reason: str) -> None:
         """Drain every live, queued, and not-yet-arrived request so a
@@ -841,9 +896,11 @@ class Engine:
             self.sched.reject(self.sched.submit(req), reason)
 
     def _step(self) -> None:
-        self._admit()
-        live = sum(t is not None for t in self.sched.slots)
-        self.stats["live_peak"] = max(self.stats["live_peak"], live)
+        with phase(self.stats, "engine.admit"):
+            self._release_arrivals()
+            self._admit()
+            live = sum(t is not None for t in self.sched.slots)
+            self.stats["live_peak"] = max(self.stats["live_peak"], live)
         prefilling = self.sched.in_state(PREFILL)
         if prefilling:
             self._chunk_prefill_step(prefilling)
@@ -914,9 +971,10 @@ class Engine:
         Non-blocking: an idle step (waiting on a future arrival) does no
         work and returns immediately."""
         n0 = len(self.sched.finished)
-        self._release_arrivals()
-        self._step()
-        self.clock.on_step()
+        with phase(None, "engine.step", step=int(self.stats["iterations"])):
+            self._step()
+            self.clock.on_step()
+            self.stats["iterations"] += 1
         return [t.result for t in self.sched.finished[n0:]]
 
     def drain(self, *, max_steps: Optional[int] = None) -> List[Result]:

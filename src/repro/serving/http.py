@@ -13,6 +13,22 @@ behind three endpoints --
   returns the whole result as one JSON object.
 * ``GET /v1/stats`` -- engine counters + per-plan breakdown + server
   gauges, sanitized finite (a mid-flight scrape must never see NaN).
+  Besides the token, step and pool counters, ``engine`` carries the
+  serving loop's own measurements (``serving/trace.py``), all running
+  sums since the last reset:
+
+  - ``iterations`` (calls of ``Engine.step``), ``steps`` (decode steps),
+    ``chunk_steps`` (chunked-prefill steps);
+  - ``host_s:<phase>``: wall seconds in each phase of the pump's
+    iteration -- ``engine.admit``, ``engine.chunk.{prepare,dispatch,
+    sample,wait,commit}``, ``engine.decode.{prepare,dispatch,sample,
+    wait,commit}``, ``server.retire``, ``server.handoff`` (the pump's
+    wait for queued control actions and the engine lock);
+  - ``host_cpu_s``: the pump thread's CPU seconds in those phases,
+    the two ``.wait`` phases (host blocked on the device) left out;
+  - ``experts_routed:l<i>``: over decode steps, the distinct experts
+    MoE layer ``i`` (in plan order) routed the live slots to, counted
+    on the device.
 * ``GET /health`` -- liveness.
 
 Threading model: ONE background *pump* thread owns engine progress -- it
@@ -52,6 +68,7 @@ import numpy as np
 
 from repro.serving.engine import Engine
 from repro.serving.request import Request, Result
+from repro.serving.trace import phase
 
 #: request-body keys POST /v1/completions accepts (anything else is a 400:
 #: a misspelled knob silently ignored would be worse than an error)
@@ -219,10 +236,11 @@ class ApiServer:
     def _retire(self) -> None:
         """Pop finished records (releasing them + their uid claims) and
         hand each result to its waiting connection.  Lock held."""
-        for res in self.engine.pop_finished():
-            comp = self._live.pop(res.uid, None)
-            if comp is not None:
-                comp.finish(res)
+        with phase(self.engine.stats, "server.retire"):
+            for res in self.engine.pop_finished():
+                comp = self._live.pop(res.uid, None)
+                if comp is not None:
+                    comp.finish(res)
 
     def _pump(self) -> None:
         """Drive ``Engine.step()`` while anything is runnable; otherwise
@@ -231,9 +249,14 @@ class ApiServer:
         pending at all (a fresh submission sets it)."""
         eng = self.engine
         while not self._stop.is_set():
-            while self._queued and not self._stop.is_set():
-                time.sleep(0)
-            with self.lock:
+            # the handoff: queued control actions go first, then the
+            # lock; the phase ends holding it, so its counter is written
+            # under it
+            with phase(eng.stats, "server.handoff"):
+                while self._queued and not self._stop.is_set():
+                    time.sleep(0)
+                self.lock.acquire()
+            try:
                 self._wake.clear()
                 nxt = eng.next_arrival()
                 runnable = (not eng.sched.done()
@@ -243,6 +266,8 @@ class ApiServer:
                     eng.step()
                     self._retire()
                     nxt = eng.next_arrival()
+            finally:
+                self.lock.release()
             if runnable:
                 continue
             if nxt is not None and not self._wake.is_set():
@@ -429,10 +454,14 @@ class _Handler(BaseHTTPRequestHandler):
                 self._end_chunks()
                 return
             kind, payload = ev
-            if kind == _DELTA:
-                self._chunk(json.dumps({"delta": payload}) + "\n")
-            else:
-                self._chunk(json.dumps(
-                    {"done": True, "result": _result_json(payload)}) + "\n")
-                self._end_chunks()
+            # a span only: this thread holds no engine lock to count under
+            with phase(None, "server.write", uid=uid):
+                if kind == _DELTA:
+                    self._chunk(json.dumps({"delta": payload}) + "\n")
+                else:
+                    self._chunk(json.dumps(
+                        {"done": True,
+                         "result": _result_json(payload)}) + "\n")
+                    self._end_chunks()
+            if kind == _DONE:
                 return

@@ -30,6 +30,12 @@ working) is the expert-tile storage dtype from ``opts``: quantized and
 bf16 engines must never share a compiled graph, because the quantized
 graphs bake in the int8/scale-row parameter layout.
 
+The graphs are jitted named functions -- ``decode_step``, ``chunk_step``,
+``prefill_step`` -- so a device trace names each program
+(``jit_decode_step``).  The decode program returns one more output than
+``decode`` does: the distinct experts each MoE layer routed the live
+slots to, which the runner keeps in ``routed`` for the engine.
+
 Per-request plans (DESIGN.md §10)
 ---------------------------------
 Every serving graph runs a **per-layer split** of the config's pattern:
@@ -127,6 +133,10 @@ class ModelRunner:
             BASE_PLAN: self._moe_ks(serve_cfg)}
         self._bucket_cfgs: Dict[Tuple[int, ...], ModelConfig] = {}
         self._jit: Dict[Tuple, Any] = {}
+        #: the last decode step's ``[n_moe]`` i32 device array: distinct
+        #: experts each MoE layer routed the live slots to (the engine
+        #: fetches it with the step's sampled tokens)
+        self.routed = None
 
     @staticmethod
     def _moe_ks(cfg: ModelConfig) -> Tuple[int, ...]:
@@ -203,12 +213,17 @@ class ModelRunner:
         ``bucket`` (per-MoE-layer static k vector) + ``k_budgets``
         ([B, n_moe] i32) select a mixed-plan bucket graph instead of
         ``plan``'s graph; surplus routed slots are zero-weighted exactly.
+
+        The step program also counts the distinct experts each MoE layer
+        routed the live slots to, within their budgets; the count stays on
+        the device in ``self.routed`` until the engine fetches it.
         """
         fn, args = self._decode_call(
             tokens, pos, caches, block_tables, plan=plan,
             use_kernel=use_kernel, kernel_blocks=kernel_blocks,
             moe_decode=moe_decode, bucket=bucket, k_budgets=k_budgets)
-        return fn(*args)
+        logits, caches, self.routed = fn(*args)
+        return logits, caches
 
     def _decode_call(self, tokens, pos, caches, block_tables=None, *,
                      plan: str = BASE_PLAN, use_kernel=None,
@@ -226,10 +241,14 @@ class ModelRunner:
             opts = dc_replace(self.opts, use_paged_kernel=uk,
                               use_moe_decode_kernel=md)
             kb = kernel_blocks
-            self._jit[key] = jax.jit(
-                lambda p, t, po, c, bt, kbud: models.decode_fn(
+
+            def decode_step(p, t, po, c, bt, kbud):
+                return models.decode_fn(
                     p, cfg, t, po, c, block_tables=bt, mesh=self.mesh,
-                    opts=opts, kernel_blocks=kb, k_budgets=kbud))
+                    opts=opts, kernel_blocks=kb, k_budgets=kbud,
+                    count_routed=True)
+            # a named function: the device trace names the program after it
+            self._jit[key] = jax.jit(decode_step)
         if bucket is not None:
             k_budgets = jnp.asarray(k_budgets, jnp.int32)
         return self._jit[key], (self.params, tokens, pos, caches,
@@ -252,10 +271,11 @@ class ModelRunner:
         head, cfg = self._resolve(plan, bucket)
         key = (head, "chunk", int(tokens.shape[1]), self.opts.expert_dtype)
         if key not in self._jit:
-            self._jit[key] = jax.jit(
-                lambda p, t, po, li, c, bt, kbud: models.chunk_prefill_fn(
+            def chunk_step(p, t, po, li, c, bt, kbud):
+                return models.chunk_prefill_fn(
                     p, cfg, t, po, c, last_index=li, block_tables=bt,
-                    mesh=self.mesh, opts=self.opts, k_budgets=kbud))
+                    mesh=self.mesh, opts=self.opts, k_budgets=kbud)
+            self._jit[key] = jax.jit(chunk_step)
         if bucket is not None:
             k_budgets = jnp.asarray(k_budgets, jnp.int32)
         return self._jit[key], (self.params, tokens, positions, last_index,
@@ -284,8 +304,9 @@ class ModelRunner:
         key = (plan, "prefill", int(tokens.shape[1]),
                self.opts.expert_dtype)
         if key not in self._jit:
-            self._jit[key] = jax.jit(
-                lambda p, t, po, c: models.prefill_fn(
+            def prefill_step(p, t, po, c):
+                return models.prefill_fn(
                     p, cfg, {"tokens": t, "positions": po}, c,
-                    mesh=self.mesh, opts=self.opts))
+                    mesh=self.mesh, opts=self.opts)
+            self._jit[key] = jax.jit(prefill_step)
         return self._jit[key](self.params, tokens, positions, caches)

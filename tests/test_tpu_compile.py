@@ -13,6 +13,7 @@ worker that runs this file loads the TPU compiler.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -95,3 +96,70 @@ def test_quantized_expert_kernels_compile(one_chip, dtype):
         xs, a, b, c, d, te, tv, dtype=dtype, block_m=BLOCK_M), one_chip,
         ((N_TILES * BLOCK_M, D), jnp.bfloat16), *tiles,
         ((N_TILES,), jnp.int32), ((N_TILES,), jnp.int32))
+
+
+# --------------------------------------------------------------------------- #
+# The step programs as the runner builds them
+# --------------------------------------------------------------------------- #
+
+#: what each step program must call, per expert dtype: the custom calls'
+#: base names, which the benchmark's trace reduction looks up
+STEP_KERNELS = {
+    ("decode", "bf16"): {"moe_decode", "flash_decode_paged"},
+    ("decode", "int8"): {"moe_decode_quant", "flash_decode_paged"},
+    ("chunk", "bf16"): {"moe_gmm"},
+    ("chunk", "int8"): {"moe_gmm_quant"},
+}
+_CUSTOM = re.compile(r"^\s*(?:ROOT )?%(?P<base>[A-Za-z_][A-Za-z0-9_\-]*?)"
+                     r"(?:\.\d+)? = .* custom-call\(")
+
+
+@pytest.mark.parametrize("dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+def test_step_programs_are_named(one_chip, monkeypatch, kind, dtype):
+    """One OLMoE layer at published widths: the runner's decode and chunk
+    programs compile as ``jit_decode_step`` / ``jit_chunk_step``, and
+    their Mosaic custom calls carry the kernel names the benchmark's
+    ``bench/trace.py`` looks for."""
+    from bench.trace import KERNELS
+    from repro import models
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models.moe import quantize_expert_params
+    from repro.models.opts import ModelOpts
+    from repro.serving.runner import ModelRunner, init_serving_params
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    cfg = get_config("olmoe-1b-7b").with_(num_layers=1)
+    params = jax.eval_shape(
+        lambda: init_serving_params(jax.random.PRNGKey(0), cfg))
+    if dtype != "bf16":
+        params = jax.eval_shape(
+            lambda p: quantize_expert_params(p, cfg, dtype), params)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    opts = ModelOpts(moe_impl="gmm", use_moe_kernel=True,
+                     use_paged_kernel=True, use_moe_decode_kernel=True,
+                     expert_dtype=dtype)
+    runner = ModelRunner(cfg, jax.tree.map(on_chip, params), opts=opts)
+    caches = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: models.init_caches(runner.cfg_for("base"), B, 2048,
+                                   layout="paged", page_size=PAGE,
+                                   num_pages=POOL_PAGES)))
+    bt = on_chip(jax.ShapeDtypeStruct((B, 2048 // PAGE), jnp.int32))
+    if kind == "decode":
+        vec = on_chip(jax.ShapeDtypeStruct((B,), jnp.int32))
+        fn, args = runner._decode_call(vec, vec, caches, bt, use_kernel=True,
+                                       kernel_blocks=8, moe_decode=True)
+    else:
+        mat = on_chip(jax.ShapeDtypeStruct((B, 128), jnp.int32))
+        vec = on_chip(jax.ShapeDtypeStruct((B,), jnp.int32))
+        fn, args = runner._chunk_call(mat, mat, vec, caches, bt)
+    text = fn.lower(*args).compile().as_text()
+    assert text.startswith(f"HloModule jit_{kind}_step")
+    mosaic = {_CUSTOM.match(line)["base"] for line in text.splitlines()
+              if 'custom_call_target="tpu_custom_call"' in line}
+    assert mosaic == STEP_KERNELS[(kind, dtype)]
+    assert mosaic <= set(KERNELS)
