@@ -275,6 +275,7 @@ class Engine:
             f"experts_routed:l{i}"
             for i in range(len(self.runner.plan_ks[BASE_PLAN])))
         self.stats: Dict[str, float] = self._fresh_stats()
+        self.runner.stats = self.stats      # the runner counts in here too
 
     def _fresh_stats(self) -> Dict[str, float]:
         # prefill_tokens counts each prompt position once (useful work);
@@ -288,11 +289,14 @@ class Engine:
         # time and host_cpu_s the pump's CPU time outside the waits
         # (serving/trace.py); experts_routed:l<i> sums, over decode steps,
         # the distinct experts MoE layer i routed the live slots to.
+        # pool_copies counts the KV pools the runner copied for callers
+        # that kept theirs (the engine hands its pool over: 0 in serving).
         out = {"prefill_tokens": 0, "decode_tokens": 0,
                "recompute_tokens": 0, "steps": 0, "preemptions": 0,
                "live_peak": 0, "prefix_hit_tokens": 0, "cow_copies": 0,
                "plan_degradations": 0, "mixed_plan_steps": 0,
-               "chunk_steps": 0, "iterations": 0, CPU_KEY: 0.0}
+               "chunk_steps": 0, "iterations": 0, "pool_copies": 0,
+               CPU_KEY: 0.0}
         out.update({WALL_PREFIX + p: 0.0 for p in PHASES})
         out.update({k: 0 for k in self._routed_keys})
         return out
@@ -755,8 +759,9 @@ class Engine:
         with phase(st, "engine.chunk.dispatch", step=int(st["chunk_steps"]),
                    plan=_plan_label(plan, bucket), tokens=n_tok):
             logits, self.kv.caches = self.runner.chunk_prefill(
-                *args, plan=plan, bucket=bucket, k_budgets=budgets)
-            del args        # the last reference to the previous pool
+                *args, plan=plan, bucket=bucket, k_budgets=budgets,
+                donate=True)
+            del args        # the donated pool: deleted by the call
             st["chunk_steps"] += 1
         with phase(st, "engine.chunk.sample"):
             for t in prefilling:    # chunk writes are committed: index them
@@ -845,8 +850,8 @@ class Engine:
             logits, self.kv.caches = self.runner.decode(
                 *args, plan=plan, use_kernel=self.use_kernel,
                 kernel_blocks=kernel_blocks, moe_decode=self.use_moe_decode,
-                bucket=bucket, k_budgets=budgets)
-            del args        # the last reference to the previous pool
+                bucket=bucket, k_budgets=budgets, donate=True)
+            del args        # the donated pool: deleted by the call
         with phase(st, "engine.decode.sample"):
             self.key, sub = jax.random.split(self.key)
             nxt = sample_per_slot(logits, sub, jnp.asarray(self.slot_temp),
@@ -922,7 +927,7 @@ class Engine:
         records mid-workload would be corrupted, not reset."""
         if not self.idle():
             raise RuntimeError("cannot reset stats with requests in flight")
-        self.stats = self._fresh_stats()
+        self.stats = self.runner.stats = self._fresh_stats()
         self.sched.clear_finished()
 
     def pop_finished(self) -> List[Result]:

@@ -36,6 +36,18 @@ The graphs are jitted named functions -- ``decode_step``, ``chunk_step``,
 ``decode`` does: the distinct experts each MoE layer routed the live
 slots to, which the runner keeps in ``routed`` for the engine.
 
+Who owns the KV pool
+--------------------
+The decode and chunk programs donate their cache argument: XLA writes the
+step's tokens into the pool's own pages instead of copying the whole pool
+into a new buffer first.  The caller says whether it gives the pool up.
+The engine, the pool's only owner, passes ``donate=True`` and replaces
+its pool with the returned one at once.  Any other caller (tests, a
+benchmark's warm-up) keeps the default: the runner copies the pool, hands
+the copy to the same donating program -- so what a warm-up compiles is
+what the engine runs -- and leaves the caller's arrays live.  Each such
+copy is counted in ``stats["pool_copies"]``; once serving, it stays 0.
+
 Per-request plans (DESIGN.md §10)
 ---------------------------------
 Every serving graph runs a **per-layer split** of the config's pattern:
@@ -137,6 +149,9 @@ class ModelRunner:
         #: experts each MoE layer routed the live slots to (the engine
         #: fetches it with the step's sampled tokens)
         self.routed = None
+        #: counters the runner keeps; the engine hands in its own stats
+        #: dict so they show beside its counters
+        self.stats: Dict[str, float] = {"pool_copies": 0}
 
     @staticmethod
     def _moe_ks(cfg: ModelConfig) -> Tuple[int, ...]:
@@ -194,6 +209,15 @@ class ModelRunner:
         """Keys of every graph compiled so far (introspection / tests)."""
         return tuple(sorted(self._jit, key=str))
 
+    def _own(self, caches, donate: bool):
+        """The pool a donating step may consume: ``caches`` itself when the
+        caller gives it up, else a copy (counted), so the caller's arrays
+        stay live."""
+        if donate:
+            return caches
+        self.stats["pool_copies"] += 1
+        return jax.tree.map(jnp.copy, caches)
+
     # ------------------------------------------------------------------ #
     # Steps
     # ------------------------------------------------------------------ #
@@ -201,7 +225,8 @@ class ModelRunner:
                plan: str = BASE_PLAN, use_kernel: Optional[bool] = None,
                kernel_blocks: Optional[int] = None,
                moe_decode: Optional[bool] = None,
-               bucket: Optional[Tuple[int, ...]] = None, k_budgets=None):
+               bucket: Optional[Tuple[int, ...]] = None, k_budgets=None,
+               donate: bool = False):
         """One decode step over all slots -> (logits [B,V], caches).
 
         ``use_kernel`` (None -> ``opts.use_paged_kernel``) selects the
@@ -217,9 +242,13 @@ class ModelRunner:
         The step program also counts the distinct experts each MoE layer
         routed the live slots to, within their budgets; the count stays on
         the device in ``self.routed`` until the engine fetches it.
+
+        ``donate=True`` gives ``caches`` up: the step updates its pages in
+        place and the caller's arrays are deleted.  Otherwise the step runs
+        on a copy and ``caches`` stays live.
         """
         fn, args = self._decode_call(
-            tokens, pos, caches, block_tables, plan=plan,
+            tokens, pos, self._own(caches, donate), block_tables, plan=plan,
             use_kernel=use_kernel, kernel_blocks=kernel_blocks,
             moe_decode=moe_decode, bucket=bucket, k_budgets=k_budgets)
         logits, caches, self.routed = fn(*args)
@@ -247,8 +276,9 @@ class ModelRunner:
                     p, cfg, t, po, c, block_tables=bt, mesh=self.mesh,
                     opts=opts, kernel_blocks=kb, k_budgets=kbud,
                     count_routed=True)
-            # a named function: the device trace names the program after it
-            self._jit[key] = jax.jit(decode_step)
+            # a named function: the device trace names the program after
+            # it; the pool (argument 3) is updated in place
+            self._jit[key] = jax.jit(decode_step, donate_argnums=3)
         if bucket is not None:
             k_budgets = jnp.asarray(k_budgets, jnp.int32)
         return self._jit[key], (self.params, tokens, pos, caches,
@@ -258,10 +288,12 @@ class ModelRunner:
     def chunk_prefill(self, tokens, positions, last_index, caches,
                       block_tables=None, *, plan: str = BASE_PLAN,
                       bucket: Optional[Tuple[int, ...]] = None,
-                      k_budgets=None):
-        """One ``[B, C]`` chunked-prefill step -> (logits [B,V], caches)."""
-        fn, args = self._chunk_call(tokens, positions, last_index, caches,
-                                    block_tables, plan=plan, bucket=bucket,
+                      k_budgets=None, donate: bool = False):
+        """One ``[B, C]`` chunked-prefill step -> (logits [B,V], caches).
+        ``donate`` as in ``decode``."""
+        fn, args = self._chunk_call(tokens, positions, last_index,
+                                    self._own(caches, donate), block_tables,
+                                    plan=plan, bucket=bucket,
                                     k_budgets=k_budgets)
         return fn(*args)
 
@@ -275,7 +307,7 @@ class ModelRunner:
                 return models.chunk_prefill_fn(
                     p, cfg, t, po, c, last_index=li, block_tables=bt,
                     mesh=self.mesh, opts=self.opts, k_budgets=kbud)
-            self._jit[key] = jax.jit(chunk_step)
+            self._jit[key] = jax.jit(chunk_step, donate_argnums=4)
         if bucket is not None:
             k_budgets = jnp.asarray(k_budgets, jnp.int32)
         return self._jit[key], (self.params, tokens, positions, last_index,

@@ -166,6 +166,7 @@ def test_stats_endpoint_carries_every_counter_finite(served):
         assert key in engine, key
         assert isinstance(engine[key], (int, float)), key
         assert math.isfinite(engine[key]), key
+    assert engine["pool_copies"] == 0       # the engine hands its pool over
     routed = [k for k in engine if k.startswith("experts_routed:l")]
     assert len(routed) == 3                 # one per MoE layer
     assert all(engine[k] > 0 for k in routed)
@@ -184,7 +185,7 @@ def test_fresh_stats_has_every_counter(setup):
     eng = Engine(cfg, params, max_batch=2, max_len=32)
     eng.serve([Request(uid=0, prompt=_prompt(5, 0), max_new_tokens=3)])
     eng.reset_stats()
-    for key in ("chunk_steps", "iterations", CPU_KEY,
+    for key in ("chunk_steps", "iterations", "pool_copies", CPU_KEY,
                 *(WALL_PREFIX + p for p in PHASES),
                 *(f"experts_routed:l{i}" for i in range(3))):
         assert eng.stats[key] == 0, key

@@ -114,14 +114,10 @@ _CUSTOM = re.compile(r"^\s*(?:ROOT )?%(?P<base>[A-Za-z_][A-Za-z0-9_\-]*?)"
                      r"(?:\.\d+)? = .* custom-call\(")
 
 
-@pytest.mark.parametrize("dtype", ["bf16", "int8"])
-@pytest.mark.parametrize("kind", ["decode", "chunk"])
-def test_step_programs_are_named(one_chip, monkeypatch, kind, dtype):
-    """One OLMoE layer at published widths: the runner's decode and chunk
-    programs compile as ``jit_decode_step`` / ``jit_chunk_step``, and
-    their Mosaic custom calls carry the kernel names the benchmark's
-    ``bench/trace.py`` looks for."""
-    from bench.trace import KERNELS
+def _step_program(one_chip, monkeypatch, kind, dtype):
+    """The runner's ``kind`` program for one OLMoE layer at published
+    widths over a ``POOL_PAGES`` pool, compiled for one chip -> (HLO
+    text, the pool's leaves as shapes)."""
     from repro import models
     from repro.configs import get_config
     from repro.kernels import ops
@@ -157,9 +153,55 @@ def test_step_programs_are_named(one_chip, monkeypatch, kind, dtype):
         mat = on_chip(jax.ShapeDtypeStruct((B, 128), jnp.int32))
         vec = on_chip(jax.ShapeDtypeStruct((B,), jnp.int32))
         fn, args = runner._chunk_call(mat, mat, vec, caches, bt)
-    text = fn.lower(*args).compile().as_text()
+    return fn.lower(*args).compile().as_text(), jax.tree.leaves(caches)
+
+
+@pytest.mark.parametrize("dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+def test_step_programs_are_named(one_chip, monkeypatch, kind, dtype):
+    """One OLMoE layer at published widths: the runner's decode and chunk
+    programs compile as ``jit_decode_step`` / ``jit_chunk_step``, and
+    their Mosaic custom calls carry the kernel names the benchmark's
+    ``bench/trace.py`` looks for."""
+    from bench.trace import KERNELS
+
+    text, _ = _step_program(one_chip, monkeypatch, kind, dtype)
     assert text.startswith(f"HloModule jit_{kind}_step")
     mosaic = {_CUSTOM.match(line)["base"] for line in text.splitlines()
               if 'custom_call_target="tpu_custom_call"' in line}
     assert mosaic == STEP_KERNELS[(kind, dtype)]
     assert mosaic <= set(KERNELS)
+
+
+_ALIAS = re.compile(r"\{[\d,]*\}: \((\d+), \{\}")
+_PARAM = re.compile(r"^\s*%\S+ = \w+(\[[\d,]*\])\S* parameter\((\d+)\)",
+                    re.M)
+_DEF = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = (.*)$", re.M)
+_COPY = re.compile(r"^\s*(?:ROOT )?%\S+ = .* copy(?:-start)?\((%[\w.\-]+)\)",
+                   re.M)
+
+
+@pytest.mark.parametrize("dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+def test_step_programs_update_the_pool_in_place(one_chip, monkeypatch, kind,
+                                                dtype):
+    """The decode and chunk programs take the KV pool over: every pool
+    leaf (``kp``, ``vp``, ``posp``) is aliased to its output, and no copy
+    reads or writes a whole ``[POOL_PAGES, 16, 16, 128]`` page leaf."""
+    text, leaves = _step_program(one_chip, monkeypatch, kind, dtype)
+    header = text.split("\n", 1)[0]
+    aliased = {int(p) for p in _ALIAS.findall(
+        header[header.index("input_output_alias="):])}
+    entry = text[text.index("\nENTRY "):]
+    params = {int(n): shape for shape, n in _PARAM.findall(entry)}
+    pool_shapes = {"[" + ",".join(map(str, x.shape)) + "]" for x in leaves}
+    pool_params = {n for n, shape in params.items() if shape in pool_shapes}
+    assert len(leaves) == len(pool_params) == 3
+    assert pool_params <= aliased
+
+    page_leaf = f"[{POOL_PAGES},{PAGE},{HKV},{HD}]"
+    defs = dict(_DEF.findall(text))
+    for m in _COPY.finditer(text):
+        line = m.group(0).split(" copy", 1)[0]      # the result's type
+        assert page_leaf not in line, m.group(0)
+        assert page_leaf not in defs[m.group(1)].split(" ", 1)[0], m.group(0)
