@@ -73,7 +73,8 @@ def _run(args) -> int:
                 dense, experts = args.extra_control.split(",")
                 x = served_gaps(weights, model, prompt, served, ks,
                                 expert_dtype=kw.get("expert_dtype"),
-                                control_dense=dense, control_experts=experts)
+                                control_dense=dense, control_experts=experts,
+                                arch=kw["arch"])
                 kept["gaps"]["extra"].append(x["control"].tolist())
         return g
 

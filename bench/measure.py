@@ -10,8 +10,10 @@ A reader (``bench/metrics/<name>.py``) gets the run as a dict:
 * ``trace``: the reduced trace (``bench/trace.py``) or None;
 * ``decode_calls`` / ``chunk_calls``: what each decode and chunk step
   worked on, by call number (the number in the step's host span);
-* ``plan_ks``: plan name -> per-layer k; ``model``: the configuration's
-  model block; ``expert_dtype``; ``peaks``: the chip's peaks.
+* ``plan_ks``: plan name -> k per MoE layer; ``model``: the
+  configuration; ``arch``: its architecture module (``bench/arch``, the one
+  the configuration's ``"arch"`` names);
+  ``expert_dtype``; ``peaks``: the chip's peaks.
 """
 
 from __future__ import annotations
@@ -87,40 +89,32 @@ def _call(run: Dict, step: Dict) -> Dict:
     return run[f"{step['kind']}_calls"][n]
 
 
-def _layer_ks(run: Dict, plans: Sequence[str], layer: int) -> List[int]:
-    return [run["plan_ks"][p][layer] for p in plans]
+def _layer_ks(run: Dict, plans: Sequence[str], moe_index: int) -> List[int]:
+    return [run["plan_ks"][p][moe_index] for p in plans]
 
 
 def kernel_work(run: Dict, kernel: str) -> List[Dict]:
     """Every traced call of ``kernel``: its operations, bytes and device
-    time, with the layer it ran for."""
-    m = run["model"]
-    kind = tr.STEP_KIND[kernel]
+    time, with the layer it ran for.  A step program calls the kernel
+    once in each layer its file's ``LAYERS`` names."""
+    m, a = run["model"], run["arch"]
+    mod = tr.KERNEL_MODULES[kernel]
+    w = a.widths(m)
+    moe = list(a.moe_layers(m))
+    layers = moe if mod.LAYERS == "moe" else list(
+        range(m["num_hidden_layers"]))
     out = []
-    for step in step_runs(run, kind):
+    for step in step_runs(run, mod.STEP):
         call = _call(run, step)
         calls = [c for c in step["kernels"] if c[0] == kernel]
-        if len(calls) != len(run["plan_ks"]["base"]):
+        if len(calls) != len(layers):
             continue            # not one call per layer: not this program
-        for layer, (_, _, dur) in enumerate(calls):
-            rec = {}
-            if kernel == "moe_decode":
-                ks = _layer_ks(run, call["plans"], layer)
-                flops, nbytes = work.moe_decode(
-                    ks, d=m["hidden_size"], f=m["intermediate_size"],
-                    e=m["num_experts"], dtype=run["expert_dtype"])
-                rec["tiles"] = work.decode_tiles(ks, m["num_experts"])
-            elif kernel == "flash_decode_paged":
-                flops, nbytes = work.flash_decode_paged(
-                    call["ctx"], heads=m["num_attention_heads"],
-                    kv_heads=m["num_key_value_heads"], hd=m["head_dim"])
-            else:
-                token_ks = [k for k, n in zip(
-                    _layer_ks(run, call["plans"], layer), call["tokens"])
-                    for _ in range(n)]
-                flops, nbytes = work.moe_gmm(
-                    token_ks, d=m["hidden_size"], f=m["intermediate_size"],
-                    e=m["num_experts"], dtype=run["expert_dtype"])
+        for layer, (_, _, dur) in zip(layers, calls):
+            ks = (_layer_ks(run, call["plans"], moe.index(layer))
+                  if layer in moe else None)
+            flops, nbytes = mod.work(w, call, ks, run["expert_dtype"])
+            rec = (mod.record(w, call, ks, run["expert_dtype"])
+                   if hasattr(mod, "record") else {})
             out.append(dict(rec, layer=layer, flops=flops, bytes=nbytes,
                             seconds=dur * 1e-9))
     return out
@@ -142,18 +136,18 @@ def model_flops(run: Dict, kind: str) -> Optional[float]:
     steps = step_runs(run, kind)
     if not steps:
         return None
-    m = run["model"]
+    m, flops = run["model"], run["arch"].model_flops
     total = 0.0
     for step in steps:
         call = _call(run, step)
         if kind == "decode":
             for plan, ctx in zip(call["plans"], call["ctx"]):
-                total += work.model_flops(m, run["plan_ks"][plan], ctx)
+                total += flops(m, run["plan_ks"][plan], ctx)
         else:
             for plan, start, n in zip(call["plans"], call["starts"],
                                       call["tokens"]):
                 for p in range(start, start + n):
-                    total += work.model_flops(m, run["plan_ks"][plan], p + 1)
+                    total += flops(m, run["plan_ks"][plan], p + 1)
     return total
 
 

@@ -1,6 +1,6 @@
 """Share of its roofline that the ``moe_decode`` kernel reached in the traced
 window, %: the least time its needed work takes at the chip's peaks
-(``bench/roofline/work.py``) over its measured device time."""
+(``bench/roofline/kernels/moe_decode.py``) over its measured device time."""
 
 from bench import measure
 

@@ -15,10 +15,12 @@ line: the cell's end-to-end metrics (``--trace 0``) or its per-layer
 metrics, read from a profiler trace of part of the window (``--trace 1``).
 
 Everything that belongs to one cell is found by name: the cell and its
-metrics in ``BENCHMARK.json``, the configuration in its file, the traffic
-mix in ``bench/traffic/<traffic>.json``, each metric's reader in
-``bench/metrics/<name>.py``.  A run that finds no TPU exits non-zero and
-prints no result.
+metrics in ``BENCHMARK.json``, the configuration in its file, its
+architecture in ``bench/arch/<arch>.py``, the traffic mix in
+``bench/traffic/<traffic>.json``, each metric's reader in
+``bench/metrics/<name>.py``, each measured kernel's work in
+``bench/roofline/kernels/<kernel>.py``.  A run that finds no TPU exits
+non-zero and prints no result.
 """
 
 from __future__ import annotations
@@ -44,6 +46,7 @@ for _p in (ROOT, os.path.join(ROOT, "src")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
+from bench import arch as archs  # noqa: E402
 from bench import measure, traffic  # noqa: E402
 
 #: seconds of the window the traced run records, starting this far in
@@ -107,8 +110,10 @@ def reader(name: str, metrics_dir: Optional[str] = None):
 # --------------------------------------------------------------------------- #
 
 
-def program_config(config: Dict):
-    """The program's model configuration, checked against the file."""
+def program_config(config: Dict, arch):
+    """The program's model configuration, checked against the file: every
+    key the architecture (``arch``) reads from the program must equal the
+    file's."""
     from repro.configs import get_config
 
     prog = config["program"]
@@ -117,24 +122,15 @@ def program_config(config: Dict):
         # every layer declared apart, as the runner serves them
         from repro.serving.runner import split_pattern
         cfg = cfg.with_(block_pattern=split_pattern(cfg))
-    want = {"hidden_size": cfg.d_model, "num_attention_heads": cfg.num_heads,
-            "num_key_value_heads": cfg.num_kv_heads,
-            "head_dim": cfg.head_dim_, "num_experts": cfg.num_experts,
-            "num_experts_per_tok": cfg.moe_top_k,
-            "intermediate_size": cfg.moe_d_ff,
-            "vocab_size": cfg.padded_vocab,
-            "num_hidden_layers": cfg.num_layers,
-            "rms_norm_eps": cfg.norm_eps, "rope_theta": cfg.rope_theta,
-            "norm_topk_prob": cfg.norm_topk_prob, "dtype": cfg.dtype}
-    diff = {k: (config[k], v) for k, v in want.items()
-            if config[k] != v}
+    diff = {k: (config.get(k), v) for k, v in arch.program_keys(cfg).items()
+            if config.get(k) != v}
     if diff:
         raise ValueError(f"the program's {prog['registry']} differs from "
                          f"the configuration file (file, program): {diff}")
     return cfg
 
 
-def build_engine(config: Dict, cfg, seed: int):
+def build_engine(config: Dict, cfg, seed: int, arch):
     """The production path, as the bring-up smoke builds it: paged KV,
     chunked prefill, on-demand pages, dropless gmm MoE, every kernel on."""
     from repro.models.opts import ModelOpts
@@ -145,7 +141,7 @@ def build_engine(config: Dict, cfg, seed: int):
     e = config["engine"]
     opts = ModelOpts(moe_impl="gmm", use_moe_kernel=True,
                      use_paged_kernel=True, use_moe_decode_kernel=True)
-    params = make_weights(config, seed)
+    params = make_weights(config, seed, arch)
     eng = Engine(cfg, params, max_batch=e["max_batch"], max_len=e["max_len"],
                  page_size=e["page_size"], prefill_chunk=e["prefill_chunk"],
                  num_pages=e.get("num_pages"), opts=opts,
@@ -296,7 +292,6 @@ def warm_up(eng, mix: Dict, requests: List[Dict]) -> None:
     page = kv.page_size
     shortest = min(len(r["prompt"]) for r in requests)
     nbs = nb_buckets(-(-shortest // page), kv.blocks_per_slot)
-    n_moe = len(eng.runner.plan_ks["base"])
     for plan, bucket in step_heads(eng, mix):
         kw = {"plan": plan}
         if bucket is not None:
@@ -362,6 +357,28 @@ def _device(require_chip: bool, chips: int):
     if require_chip and len(devs) < chips:
         raise NoChip(f"the cell asks for {chips} chips, JAX sees "
                      f"{len(devs)}")
+    return devs
+
+
+def open_device(chips: int, require_chip: bool, root: str = ROOT,
+                log=None):
+    """The devices, past the look for a chip; on the chip, with every
+    program kept in the compile cache at one fixed path inside the
+    checkout (which the program takes from ``JAX_COMPILATION_CACHE_DIR``)."""
+    if require_chip:
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(root,
+                                                               ".jax_cache")
+    devs = _device(require_chip, chips)
+    if require_chip:
+        import jax
+
+        from repro.launch.compile_cache import enable_compile_cache
+        cache = enable_compile_cache()
+        jax.config.update("jax_compilation_cache_dir", cache)
+        if log:
+            log(f"compile cache: {cache}")
+        # every program is worth caching: the window must compile nothing
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     return devs
 
 
@@ -450,7 +467,8 @@ def sample_requests(records: List[Dict], requests: List[Dict], n: int,
 
 
 def check_outputs(config: Dict, seed: int, records: List[Dict],
-                  requests: List[Dict], mix: Dict, *, control: bool = False):
+                  requests: List[Dict], mix: Dict, *, arch,
+                  control: bool = False):
     """Compare a sample of finished greedy requests with the reference.
 
     The sample is drawn from the seed and always holds the longest
@@ -469,19 +487,18 @@ def check_outputs(config: Dict, seed: int, records: List[Dict],
     pick = sample_requests(records, requests, int(mix["check_requests"]),
                            seed)
     model, e = config, config["engine"]
-    weights = make_weights(model, seed)
+    weights = make_weights(model, seed, arch)
     served, ctl_gaps = [], []
     ctl = config["check"]["control"] if control else {}
+    base = [model["num_experts_per_tok"]] * len(arch.moe_layers(model))
     for r in pick:
         res = r["result"]
-        ks = config["plans"].get(res["served_plan"],
-                                 [model["num_experts_per_tok"]]
-                                 * model["num_hidden_layers"])
+        ks = config["plans"].get(res["served_plan"], base)
         g = reference.served_gaps(
             weights, model, by_id[r["id"]]["prompt"], res["tokens"], ks,
             expert_dtype=e["expert_dtype"],
             control_dense=ctl.get("dense"),
-            control_experts=ctl.get("experts"))
+            control_experts=ctl.get("experts"), arch=arch)
         served.append(g["served"])
         if control:
             ctl_gaps.append(g["control"])
@@ -516,34 +533,23 @@ def readings(gaps: List) -> Dict:
 def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
              require_chip: bool = True, root: str = ROOT,
              bench: Optional[Dict] = None, traffic_dir: Optional[str] = None,
-             control: bool = False, save_trace: Optional[str] = None,
-             log=None) -> Dict:
+             arch_dir: Optional[str] = None, control: bool = False,
+             save_trace: Optional[str] = None, log=None) -> Dict:
     """One run of cell ``name``; returns the result line's object."""
     log = log or (lambda *a: print(*a, file=sys.stderr, flush=True))
     bench = bench or load_benchmark(root)
     cell, config, mix = find_cell(bench, name, root, traffic_dir)
-    if require_chip:
-        # the compile cache at one fixed path inside the checkout, which the
-        # program takes from this variable
-        os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(root,
-                                                               ".jax_cache")
-    devs = _device(require_chip, int(cell["chips"]))
+    arch = archs.of(config, arch_dir)
+    devs = open_device(int(cell["chips"]), require_chip, root, log)
     import jax
 
     from bench.roofline import work
-    peaks = work.peaks(devs[0].device_kind) if require_chip else None
-    from repro.launch.compile_cache import enable_compile_cache
     from repro.serving import ApiServer
 
-    if require_chip:
-        cache = enable_compile_cache()
-        jax.config.update("jax_compilation_cache_dir", cache)
-        log(f"compile cache: {cache}")
-        # every program is worth caching: the window must compile nothing
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    peaks = work.peaks(devs[0].device_kind) if require_chip else None
     counts = CompileCount()
-    cfg = program_config(config)
-    eng = build_engine(config, cfg, seed)
+    cfg = program_config(config, arch)
+    eng = build_engine(config, cfg, seed, arch)
     e = config["engine"]
     requests = traffic.generate(mix, seed=seed,
                                 vocab_size=config["vocab_size"],
@@ -585,7 +591,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         f" {(jax.devices()[0].memory_stats() or {}).get('bytes_in_use')}")
     run.update(setup_s=setup_s, trace=red, plan_ks=plan_ks,
                decode_calls=instr.decode_calls,
-               chunk_calls=instr.chunk_calls, model=config,
+               chunk_calls=instr.chunk_calls, model=config, arch=arch,
                expert_dtype=e["expert_dtype"], peaks=peaks)
     log(f"window {seconds} s: {len(measure.token_gaps(run))} token gaps, "
         f"{len(measure.due_in_window(run))} requests due, "
@@ -625,10 +631,14 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
         "token_gaps": len(measure.token_gaps(run)),
         "itl_ms": {f"p{q}": 1e3 * (measure.pct(measure.token_gaps(run), q)
                                    or 0.0) for q in (50, 90, 95, 99)},
+        "ttft_ms": {f"p{q}": 1e3 * (measure.pct(
+            [r["first"] - r["due"] for r in due if r["first"] is not None],
+            q) or 0.0) for q in (50, 75, 90)},
         "compiles_in_window": in_window[1], "traces_in_window": in_window[0],
     }
     ok, compared, read = check_outputs(config, seed, run["records"],
-                                       requests, mix, control=control)
+                                       requests, mix, control=control,
+                                       arch=arch)
     out["correct"] = bool(ok and not failed)
     out["readings"] = read
     out["compared"] = compared

@@ -8,7 +8,9 @@ import shutil
 
 import pytest
 
-from bench import run, traffic
+from bench import arch, run, traffic
+from bench import trace as tr
+from bench.roofline import kernels
 
 ROOT = run.ROOT
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -58,7 +60,8 @@ def test_config_files_are_the_programs(bench):
     for c in bench["configs"]:
         with open(os.path.join(ROOT, c["file"])) as f:
             config = json.load(f)
-        cfg = run.program_config(config)        # raises on a difference
+        # raises on a difference
+        cfg = run.program_config(config, arch.of(config))
         assert cfg.num_layers == config["num_hidden_layers"]
         assert set(c["reduced"]) <= set(config["reduced"])
 
@@ -87,3 +90,44 @@ def test_a_new_metric_file_is_found(tmp_path):
 def test_unknown_cell_raises(bench):
     with pytest.raises(KeyError, match="no workload"):
         run.find_cell(bench, "no.such.cell")
+
+
+ARCH_API = ("leaf_specs", "logits", "program_keys", "moe_layers", "widths",
+            "model_flops")
+
+
+def test_every_config_names_an_architecture(bench):
+    for c in bench["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            config = json.load(f)
+        mod = arch.of(config)
+        assert all(callable(getattr(mod, fn)) for fn in ARCH_API)
+        assert set(mod.moe_layers(config)) <= set(
+            range(config["num_hidden_layers"]))
+
+
+def test_an_unknown_architecture_raises():
+    with pytest.raises(KeyError, match="no architecture"):
+        arch.load("no_such_arch")
+
+
+def test_every_kernel_file_declares_its_work():
+    assert set(tr.KERNEL_MODULES) == {"moe_decode", "moe_gmm",
+                                      "flash_decode_paged"}
+    for name, mod in tr.KERNEL_MODULES.items():
+        assert name in mod.OPS and all(tr.KERNELS[op] == name
+                                       for op in mod.OPS)
+        assert mod.STEP in ("decode", "chunk")
+        assert mod.LAYERS in ("all", "moe")
+        assert callable(mod.work)
+
+
+def test_a_new_kernel_file_is_found(tmp_path):
+    (tmp_path / "new_decode_attention.py").write_text(
+        'OPS = ("new_decode_attention",)\nSTEP = "decode"\n'
+        'LAYERS = "all"\n\n\ndef work(w, call, ks, dtype):\n'
+        '    return 1.0, 2.0\n')
+    found = kernels.load_all(str(tmp_path))
+    assert list(found) == ["new_decode_attention"]
+    assert found["new_decode_attention"].work({}, {}, None, "bf16") == (
+        1.0, 2.0)

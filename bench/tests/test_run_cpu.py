@@ -5,9 +5,13 @@ the metrics and the comparison with the reference.
 The sound run must come out correct; the lower-precision control, read on
 the same tokens, must not; and so must not a run whose engine alters the
 tokens it samples, whose decode step drops its KV writes, or whose decode
-step gives half of the batch the other half's logits.
+step gives half of the batch the other half's logits.  An architecture
+that only the tests' own files define (a leading dense layer) runs
+through the same harness, correct when sound and not when its dense
+layer's output is zeroed.
 """
 
+import functools
 import json
 import os
 
@@ -17,17 +21,25 @@ import pytest
 from bench import run
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
+#: architectures of the tests' own (``olmoe_dense_first``)
+ARCH = os.path.join(DATA, "arch")
 TINY = {
     "configs": [{"name": "tiny", "file": "bench/tests/data/tiny.json"},
                 {"name": "tiny-int8",
-                 "file": "bench/tests/data/tiny-int8.json"}],
+                 "file": "bench/tests/data/tiny-int8.json"},
+                {"name": "tiny-dense-first",
+                 "file": "bench/tests/data/tiny-dense-first.json"}],
     "workloads": [
         {"name": "tiny.closed", "config": "tiny", "traffic": "tiny_closed",
          "chips": 1},
         {"name": "tiny.open", "config": "tiny", "traffic": "tiny_open",
          "chips": 1},
+        {"name": "tiny.closed-all", "config": "tiny",
+         "traffic": "tiny_closed_all", "chips": 1},
         {"name": "tiny-int8.closed", "config": "tiny-int8",
-         "traffic": "tiny_closed_base", "chips": 1}],
+         "traffic": "tiny_closed_base", "chips": 1},
+        {"name": "tiny-dense-first.closed", "config": "tiny-dense-first",
+         "traffic": "tiny_closed", "chips": 1}],
     "end_to_end": [
         {"name": "output_tok_s", "unit": "tokens/s"},
         {"name": "itl_p95_ms", "unit": "ms"},
@@ -73,6 +85,28 @@ def test_int8_experts_run_and_compare():
     assert out["readings"]["control"]["mean_gap"] > 3 * limit
 
 
+def test_a_new_architecture_runs_from_its_files_alone():
+    # a leading dense layer: 3 layers, 2 of them MoE, the plan's k per MoE
+    # layer; the harness takes the architecture from its module alone
+    out = _run("tiny-dense-first.closed", 2**31 + 11, arch_dir=ARCH,
+               control=True)
+    assert out["correct"] is True
+    limit = out["compared"]["mean_gap"]["limit"]
+    assert out["readings"]["control"]["mean_gap"] > 3 * limit
+
+
+def test_a_new_architecture_with_its_dense_layer_zeroed_is_caught(
+        monkeypatch):
+    import jax.numpy as jnp
+    import repro.models.blocks as blocks
+
+    monkeypatch.setattr(blocks, "mlp", lambda params, x: jnp.zeros_like(x))
+    out = _run("tiny-dense-first.closed", 2**31 + 11, arch_dir=ARCH)
+    assert out["correct"] is False
+    assert out["compared"]["mean_gap"]["value"] > \
+        out["compared"]["mean_gap"]["limit"]
+
+
 def test_altered_tokens_are_caught(monkeypatch):
     import repro.serving.engine as engine_mod
 
@@ -90,11 +124,15 @@ def test_altered_tokens_are_caught(monkeypatch):
     assert set(out["metrics"]) == {"output_tok_s", "itl_p95_ms", "setup_s"}
 
 
-def _state_unchanged(logits, caches, new_caches):
-    return logits, caches           # the step's KV writes are dropped
+def _state_unchanged(step, tokens, pos, caches, *a, **kw):
+    # the step runs on a copy of the pool (the engine's is not donated)
+    # and its KV writes are dropped: the engine keeps the pool it had
+    logits, _ = step(tokens, pos, caches, *a, **dict(kw, donate=False))
+    return logits, caches
 
 
-def _half_batch(logits, caches, new_caches):
+def _half_batch(step, *a, **kw):
+    logits, new_caches = step(*a, **kw)
     half = logits.shape[0] // 2     # the upper slots get the lower ones'
     return logits.at[half:].set(logits[:half]), new_caches
 
@@ -106,12 +144,13 @@ def test_broken_decode_step_is_caught(monkeypatch, fault):
 
     real = ModelRunner.decode
 
-    def broken(self, tokens, pos, caches, *a, **kw):
-        logits, new_caches = real(self, tokens, pos, caches, *a, **kw)
-        return fault(logits, caches, new_caches)
+    def broken(self, *a, **kw):
+        return fault(functools.partial(real, self), *a, **kw)
 
     monkeypatch.setattr(ModelRunner, "decode", broken)
-    out = _run("tiny.closed", 13)
+    # every finished request is compared: a fault that alters only the
+    # upper slots' tokens shows whichever requests the seed's sample holds
+    out = _run("tiny.closed-all", 13)
     assert out["correct"] is False
     assert out["compared"]["mean_gap"]["value"] > \
         out["compared"]["mean_gap"]["limit"]

@@ -113,3 +113,17 @@ def test_bad_mix_is_refused():
         traffic.validate(dict(mix, burst=3))
     with pytest.raises(ValueError, match="rate_rps"):
         traffic.validate(dict(mix, rate_rps=0))
+
+
+def test_open_loop_sends_the_same_schedule_for_every_seed():
+    # the window holds a few tens of requests: the seed must not choose
+    # which sizes fall in it
+    mix = _mix("tiny_open")
+
+    def schedule(seed):
+        return [(round(r["due"], 9), len(r["prompt"]), r["max_new"],
+                 r["plan"], r["temperature"])
+                for r in traffic.generate(mix, seed=seed, vocab_size=50304,
+                                          max_len=2048)]
+
+    assert schedule(1) == schedule(BIG_SEED)

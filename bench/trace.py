@@ -9,10 +9,10 @@ clock:
 * ``host``: the benchmark's own host spans (names starting ``bench.``),
   which it wraps around the engine's and the runner's calls.
 
-The program names nothing yet: both step programs are ``jit(<lambda>)``.
-So a program run is told apart by the kernels inside it (the paged
-decode kernel or the fused routed-expert kernel make a decode step, the
-grouped-matmul kernel a chunk step), and a kernel by its custom call's
+A program run is told apart by the kernels inside it (each kernel file
+under ``bench/roofline/kernels/`` says which step its calls make: the
+paged decode kernel or the fused routed-expert kernel make a decode step,
+the grouped-matmul kernel a chunk step), and a kernel by its custom call's
 instruction name, which carries the kernel wrapper's name
 (``%moe_decode.15 = bf16[16,2048]{...} custom-call(s32[16,8]...``).  Each
 step run is matched to the host span of the call that launched it: the
@@ -27,13 +27,15 @@ import os
 import re
 from typing import Dict, List, Optional, Tuple
 
+from bench.roofline import kernels
+
+#: kernel -> its file's module (``bench/roofline/kernels/<kernel>.py``)
+KERNEL_MODULES = kernels.load_all()
 #: custom-call instruction base name -> kernel, for the kernels measured
-KERNELS = {"moe_decode": "moe_decode", "moe_decode_quant": "moe_decode",
-           "moe_gmm": "moe_gmm", "moe_gmm_quant": "moe_gmm",
-           "flash_decode_paged": "flash_decode_paged"}
+KERNELS = {op: name for name, mod in KERNEL_MODULES.items()
+           for op in mod.OPS}
 #: which kernels make a program run a decode or a chunk step
-STEP_KIND = {"moe_decode": "decode", "flash_decode_paged": "decode",
-             "moe_gmm": "chunk"}
+STEP_KIND = {name: mod.STEP for name, mod in KERNEL_MODULES.items()}
 
 _OP = re.compile(r"^%(?P<base>[A-Za-z_][A-Za-z0-9_\-]*?)(?:\.\d+)? = ")
 _OPCODE = re.compile(r"^ (?P<opcode>[a-z][a-z\-]*)\((?P<args>.*)$")

@@ -2,16 +2,17 @@
 
 A mix is a JSON file under ``bench/traffic/`` (keys below).  Every seed
 gets the same multiset of sizes, plans, sampling modes and arrival gaps --
-drawn as fixed quantiles of the mix's distributions -- in another order,
-and its own prompt tokens.  So two seeds do the same work, and a seed's
-runs repeat exactly.
+drawn as fixed quantiles of the mix's distributions -- and its own prompt
+tokens.  So two seeds do the same work, and a seed's runs repeat exactly.
 
-In a closed loop only the first few requests of each client fall in the
-window, so an order drawn from the seed would change the work there.
-Each client's sequence of sizes, plans and modes is therefore the same
-for every seed, and the seed decides which client runs which sequence
-(the clients are alike) and draws the tokens.  An open loop sends its
-whole set in the seed's order.
+Only part of the set falls in the window, so an order drawn from the seed
+would change the work there: in a closed loop, the first few requests of
+each client; in an open loop, the requests due in it (a few tens at a
+chat rate, whose heavy-tailed sizes then swing the window's tokens and
+tails from seed to seed).  So the order is the same for every seed.  In a
+closed loop each client's sequence of sizes, plans and modes is fixed and
+the seed decides which client runs which sequence (the clients are
+alike); an open loop sends the fixed schedule.  The seed draws the tokens.
 
 Keys of a mix:
 
@@ -42,7 +43,7 @@ from typing import Dict, List
 
 import numpy as np
 
-#: the seed of a closed loop's order of sizes, the same for every run
+#: the seed of the order of sizes and arrivals, the same for every run
 FIXED_ORDER = 0
 
 _KEYS = {"loop", "clients", "rate_rps", "requests", "warmup_s", "prompt_len",
@@ -109,7 +110,7 @@ def generate(mix: Dict, *, seed: int, vocab_size: int,
     n = int(mix["requests"])
     rng = np.random.default_rng(seed)
     closed = mix["loop"] == "closed"
-    order = np.random.default_rng(FIXED_ORDER) if closed else rng
+    order = np.random.default_rng(FIXED_ORDER)
     prompt = lognormal_set(mix["prompt_len"], n)[order.permutation(n)]
     output = lognormal_set(mix["output_len"], n)[order.permutation(n)]
     plans = _dealt(zip([p for p, _ in mix["plans"]],
@@ -122,7 +123,7 @@ def generate(mix: Dict, *, seed: int, vocab_size: int,
         client_of = rng.permutation(int(mix["clients"]))
     if mix["loop"] == "open":
         gaps = -np.log1p(-quantiles(n)) / float(mix["rate_rps"])
-        due = np.cumsum(gaps[rng.permutation(n)])
+        due = np.cumsum(gaps[order.permutation(n)])
     reqs = []
     for i in range(n):
         p = int(min(prompt[i], max_len - 2))
